@@ -65,6 +65,9 @@ def run(full: bool = False, tiny: bool = False):
             f"{flags} --xla_force_host_platform_device_count="
             f"{FORCED_DEVICES}").strip()
     env.setdefault("PYTHONPATH", "src")
+    # forced host devices: the child must never contend for an accelerator
+    # the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(args, env=env)
     if proc.returncode != 0:
         raise RuntimeError(

@@ -112,26 +112,28 @@ def checkified_spec_fns(spec, k: int):
 @lru_cache(maxsize=None)
 def _checkified_spec_fns(spec, k: int):
     from ..core.compaction import spec_fns
+    from ..core.problem import lane_map
 
     _, init, _, conv, _ = spec_fns(spec, k)
     invariants = _INVARIANTS[spec.name]
 
-    # vmap OUTSIDE checkify everywhere: checkify cannot rewrite a batched
-    # while-loop (checkify-of-vmap-of-while is unsupported, and the
-    # epilogues run completion loops too), but vmap-of-checkify batches
-    # the error value per lane and ``throw()`` reports the first failed
-    # lane's message.
-    ck_prologue = jax.jit(lambda ops: jax.vmap(
-        checkify.checkify(spec.prologue, errors=ERRORS))(ops))
-
+    # Checkify goes inside the batching: it cannot rewrite a batched
+    # while-loop (checkify-of-vmap-of-while). The error value comes out
+    # stacked per lane and ``throw()`` reports the first failed lane's
+    # message. The prologue is vmapped and the epilogue lane-mapped,
+    # exactly as in production, so their float reductions match it bit
+    # for bit. The chunk's lanes also run one after another: vmap-of-
+    # checkify fails to batch the phase loop's nested while-loops, and the
+    # chunk is integer-exact, so the lane order cannot change its result.
     def one(d, s):
         invariants(d, s)
         return spec.run_phases(d, s, k)
 
-    ck_one = checkify.checkify(one, errors=CHUNK_ERRORS)
-    ck_chunk = jax.jit(lambda data, state: jax.vmap(ck_one)(data, state))
-    ck_epilogue = jax.jit(lambda ctx, state: jax.vmap(
-        checkify.checkify(spec.epilogue, errors=CHUNK_ERRORS))(ctx, state))
+    ck_prologue = jax.jit(jax.vmap(
+        checkify.checkify(spec.prologue, errors=ERRORS)))
+    ck_chunk = jax.jit(lane_map(checkify.checkify(one, errors=CHUNK_ERRORS)))
+    ck_epilogue = jax.jit(lane_map(
+        checkify.checkify(spec.epilogue, errors=CHUNK_ERRORS)))
 
     return (_throwing(ck_prologue), init, _throwing(ck_chunk), conv,
             _throwing(ck_epilogue))

@@ -4,8 +4,8 @@
 work — a ragged list of instances or one pre-batched bucket — through a
 single code path to whichever driver the :class:`DispatchPolicy` selects:
 
-  * ``lockstep``   the PR-1 fixed-shape vmapped while_loop (one dispatch,
-                   every lane runs until the slowest converges);
+  * ``lockstep``   the PR-1 fixed-shape vmapped while_loop (one phase-loop
+                   dispatch, every lane runs until the slowest converges);
   * ``compact``    the convergence-compacting chunked-phase driver
                    (core/compaction.py) — per-instance eps supported;
   * ``mesh``       the mesh-distributed compacting driver

@@ -4,7 +4,8 @@ The paper's headline bound is *parallel* time O(log n / eps^2); serving many
 small/medium OT instances means the win comes from amortizing one dispatch
 across a batch (cf. the matrix-batched formulations of Altschuler-Weed-
 Rigollet).  This module vmaps the existing single-instance ``lax.while_loop``
-solvers over a leading batch axis.  JAX's while-loop batching rule runs the
+solvers over a leading batch axis (the float epilogue is the compacting
+driver's own program).  JAX's while-loop batching rule runs the
 lockstep loop until every instance's own predicate is false and select-masks
 the carries of finished instances, so each instance executes *exactly* the
 phase sequence it would have executed alone - results are bit-identical to
@@ -39,18 +40,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from .problem import (
+    ASSIGNMENT,
+    OT,
     _mask_ot_inputs,
     _sizes_arrays,
     _theta_array,
     pow2_at_least,
 )
-from .pushrelabel import (
-    assignment_epilogue,
-    assignment_pipeline,
-    assignment_prologue,
-    solve_assignment_int,
-)
-from .transport import OTResult, ot_pipeline
+from .pushrelabel import assignment_prologue, solve_assignment_int
+from .transport import OTResult, ot_phase_cap, ot_prologue, solve_ot_int
 
 DEFAULT_BUCKETS: tuple[int, ...] = (16, 32, 64, 128, 256, 512, 1024, 2048)
 
@@ -79,30 +77,27 @@ class BatchedAssignmentResult(NamedTuple):
     matched_before_completion: jnp.ndarray  # (B,) int32
 
 
-@partial(jax.jit, static_argnames=("eps",))
-def _solve_assignment_batched(c, m_valid, n_valid, threshold, eps: float):
-    return jax.vmap(
-        lambda ci, mv, nv, th: assignment_pipeline(
-            ci, eps, m_valid=mv, n_valid=nv, threshold=th
-        )
-    )(c, m_valid, n_valid, threshold)
+def _epilogue(spec):
+    """The compacting driver's epilogue program, so lockstep results equal
+    compact (and mesh) results bit for bit on every backend."""
+    from .compaction import DEFAULT_CHUNK, spec_fns
+
+    return spec_fns(spec, DEFAULT_CHUNK)[4]
 
 
 @partial(jax.jit, static_argnames=("eps",))
-def _solve_assignment_batched_state(c, m_valid, n_valid, threshold,
-                                    eps: float):
-    """``_solve_assignment_batched`` that ALSO returns the pre-completion
-    integer state — the same prologue -> solve_assignment_int -> epilogue
-    composition ``assignment_pipeline`` is made of, so the per-instance
-    trajectory (and the result) is identical; only the state escapes the
-    program. Used when the Solution surface requests the ``state``
-    artifact (want/keep_state) under lockstep dispatch."""
+def _assignment_lockstep(c, m_valid, n_valid, threshold, eps: float):
+    """Prologue + integer solve of every lane in ONE vmapped program;
+    returns the ``ASSIGNMENT`` epilogue context and the pre-completion
+    integer state."""
 
     def one(ci, mv, nv, th):
         cm, c_int, scale, row_ok, col_ok = assignment_prologue(
             ci, eps, mv, nv)
         st = solve_assignment_int(c_int, eps, m_valid=mv, threshold=th)
-        return assignment_epilogue(cm, scale, st, eps, row_ok, col_ok), st
+        ctx = {"cm": cm, "scale": scale, "row_ok": row_ok,
+               "col_ok": col_ok, "eps": jnp.float32(eps)}
+        return ctx, st
 
     return jax.vmap(one)(c, m_valid, n_valid, threshold)
 
@@ -140,11 +135,8 @@ def solve_assignment_batched(
     threshold = np.asarray([int(eps * int(mi)) for mi in m_valid], np.int32)
     args = (c, jnp.asarray(m_valid), jnp.asarray(n_valid),
             jnp.asarray(threshold))
-    state = None
-    if keep_state:
-        r, state = _solve_assignment_batched_state(*args, eps)
-    else:
-        r = _solve_assignment_batched(*args, eps)
+    ctx, state = _assignment_lockstep(*args, eps)
+    r = _epilogue(ASSIGNMENT)(ctx, state)
     out = BatchedAssignmentResult(
         matching=r.matching,
         cost=r.cost,
@@ -162,11 +154,21 @@ def solve_assignment_batched(
 # --------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("eps",))
-def _solve_ot_batched(c, nu, mu, theta, threshold, eps: float) -> OTResult:
-    return jax.vmap(
-        lambda ci, nui, mui, ti, thi: ot_pipeline(ci, nui, mui, ti, eps,
-                                                  threshold=thi)
-    )(c, nu, mu, theta, threshold)
+def _ot_lockstep(c, nu, mu, theta, threshold, eps: float):
+    """Prologue + integer solve of every lane in ONE vmapped program;
+    returns the ``OT`` epilogue context and the integer state."""
+
+    def one(ci, nui, mui, ti, thi):
+        c_int, s_int, d_int, scale = ot_prologue(ci, nui, mui, ti, eps)
+        nb, na = ci.shape
+        st = solve_ot_int(c_int, s_int, d_int, eps, ot_phase_cap(eps),
+                          max_rounds=int(nb + na + 2), threshold=thi)
+        ctx = {"c": ci, "nu": nui, "mu": mui, "theta": ti,
+               "eps": jnp.float32(eps), "scale": scale, "s_int": s_int,
+               "d_int": d_int}
+        return ctx, st
+
+    return jax.vmap(one)(c, nu, mu, theta, threshold)
 
 
 def solve_ot_batched(
@@ -203,8 +205,9 @@ def solve_ot_batched(
     m_valid, n_valid = _sizes_arrays(sizes, b, m, n)
     th = _theta_array(m_valid, n_valid, eps, theta)
     c, nu, mu, thr = _mask_ot_inputs(c, nu, mu, m_valid, n_valid, th, eps)
-    return _solve_ot_batched(c, nu, mu, jnp.asarray(th), jnp.asarray(thr),
-                             eps)
+    ctx, state = _ot_lockstep(c, nu, mu, jnp.asarray(th), jnp.asarray(thr),
+                              eps)
+    return _epilogue(OT)(ctx, state)
 
 
 # --------------------------------------------------------------------------

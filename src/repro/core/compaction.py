@@ -54,7 +54,7 @@ import numpy as np
 # timing and deadline checks here share a time base with the scheduler's
 # spans, submit timestamps, and per-request deadlines
 from ..obs.metrics import now as _now
-from .problem import ASSIGNMENT, OT, pow2_at_least
+from .problem import ASSIGNMENT, OT, lane_map, pow2_at_least
 
 DEFAULT_CHUNK = 8
 
@@ -247,8 +247,7 @@ def spec_fns(spec, k: int):
     conv = jax.jit(
         lambda data, state: (jax.vmap(spec.converged)(data, state),
                              state.phases))
-    epilogue = jax.jit(
-        lambda ctx, state: jax.vmap(spec.epilogue)(ctx, state))
+    epilogue = jax.jit(lane_map(spec.epilogue))
     return prologue, init, chunk, conv, epilogue
 
 

@@ -26,10 +26,11 @@ power-of-two bucket descent in core/compaction.py):
     batch axis is always divisible by the mesh;
   * between dispatches the (B,) converged mask is fetched with one global
     gather; when occupancy has halved, ALL lanes are flushed into the
-    full-size sharded result buffer and the survivors are gathered and
-    EXPLICITLY ``device_put`` onto the next power-of-two bucket's
-    ``NamedSharding(P(batch_axis))`` — re-bucketing is a host-driven
-    re-shard, never an implicit layout change;
+    full-size sharded result buffer (each device copies its own lanes out
+    of the replicated bucket, see ``_place_into``) and the survivors are
+    gathered and EXPLICITLY ``device_put`` onto the next power-of-two
+    bucket's ``NamedSharding(P(batch_axis))`` — re-bucketing is a
+    host-driven re-shard, never an implicit layout change;
   * once the next bucket would drop below the device count
     (``pow2_at_least(live) < D``), the surviving lanes are collapsed onto
     a single device (replicated single-device dispatch) and the remaining
@@ -47,8 +48,10 @@ most of the mesh idle).
 Under batch placement, per-lane results are BIT-IDENTICAL to the
 single-device compacting driver (and hence to lockstep batched and
 unbatched solves): shard_map lanes never interact, the proposal hash keys
-depend only on the within-instance (row, col, phase), and
-retirement/re-sharding of a neighbor cannot perturb a survivor. ``eps``
+depend only on the within-instance (row, col, phase), retirement/re-sharding
+of a neighbor cannot perturb a survivor, and each device runs the float
+epilogue lane by lane (``problem.lane_map``, as the compacting driver does),
+so a lane's plan does not depend on how many lanes share its device. ``eps``
 may be a per-instance (B,) array, as in the compacting driver. Under
 matrix placement each instance solves at its own mesh-divisible padded
 shape, so the INTEGER state (matching, duals, flows, phase counts) is
@@ -80,9 +83,9 @@ from .problem import (
     OT,
     _sizes_arrays,
     eps_array,
+    lane_map,
     pow2_at_least,
 )
-from ..compat import shard_map as _shard_map
 from ..obs.metrics import now as _now
 
 
@@ -159,7 +162,7 @@ def _matrix_mesh(mesh: Mesh) -> Tuple[Mesh, str, str]:
 def _wrap(mesh: Mesh, axis: str, fn, donate=()):
     spec = P(axis)
     return jax.jit(
-        _shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec),
+        jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec),
         donate_argnums=donate,
     )
 
@@ -184,22 +187,28 @@ def _mesh_fns(spec, mesh: Mesh, axis: str, k: int):
     conv = _wrap(mesh, axis,
                  lambda data, state: (jax.vmap(spec.converged)(data, state),
                                       state.phases))
-    epilogue = _wrap(mesh, axis,
-                     lambda ctx, state: jax.vmap(spec.epilogue)(ctx, state))
+    epilogue = _wrap(mesh, axis, lane_map(spec.epilogue))
     return prologue, init, chunk, conv, epilogue
 
 
 @lru_cache(maxsize=None)
-def _scatter_to(sh):
-    """Scatter ``tree`` into ``buf`` at rows ``idx`` with the result pinned
-    to ``sh`` (the full-size buffer keeps its batch sharding even when the
-    incoming lanes live on a single collapsed device)."""
-    return jax.jit(
-        lambda buf, tree, idx: jax.tree_util.tree_map(
-            lambda b, a: b.at[idx].set(a), buf, tree
-        ),
-        out_shardings=sh,
-    )
+def _place_into(mesh: Mesh, axis: str):
+    """Write a bucket's lanes back into the full-size sharded result
+    buffer: ``buf[j] = tree[pos[j]]`` wherever ``pos[j] >= 0``. The bucket
+    arrives replicated and each device picks its own lanes with a local
+    gather, so no partitioned scatter runs: on four TPU v5e chips the
+    partitioned scatter of a bucket into the sharded buffer corrupted the
+    duals and free masses of some lanes."""
+    def place(buf, tree, pos):
+        def one(b, a):
+            keep = (pos >= 0).reshape(pos.shape + (1,) * (b.ndim - 1))
+            return jnp.where(keep, a[jnp.maximum(pos, 0)], b)
+
+        return jax.tree_util.tree_map(one, buf, tree)
+
+    return jax.jit(jax.shard_map(place, mesh=mesh,
+                                 in_specs=(P(axis), P(), P(axis)),
+                                 out_specs=P(axis)))
 
 
 def _put(tree, target):
@@ -239,16 +248,16 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
     cur_d, cur_s = data, state
     sharded = d0 > 1
 
-    def flush(buf, tree, idx, sharded):
+    def flush(buf, tree, idx):
         if buf is None:
             # first flush: idx is still the identity, buf IS the state
             return tree
-        if not sharded:
-            # collapsed lanes live on one device; replicate them onto the
-            # mesh so the scatter into the still-sharded buffer is one
-            # mesh-wide program
-            tree = _put(tree, sh_rep)
-        return _scatter_to(sh)(buf, tree, jnp.asarray(idx))
+        # buffer lane -> bucket lane (filler duplicates hold one converged
+        # lane's state, so any of them will do)
+        pos = np.full((stats.dispatched_batch,), -1, np.int32)
+        pos[idx] = np.arange(idx.shape[0], dtype=np.int32)
+        return _place_into(mesh, axis)(buf, _put(tree, sh_rep),
+                                       jax.device_put(pos, sh))
 
     ph_prev = np.zeros((stats.dispatched_batch,), np.int64)
     for _ in range(max_chunks):
@@ -285,7 +294,7 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
                       compiled=cache_now - cache_prev.get(id(run_fn), 0))
             cache_prev[id(run_fn)] = cache_now
         if live == 0:
-            buf = flush(buf, cur_s, idx, sharded)
+            buf = flush(buf, cur_s, idx)
             break
         if deadline is not None and _now() + t_chunk >= deadline:
             # earliest deadline at risk: stop dispatching, flush best-so-
@@ -297,14 +306,14 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
             stats.unconverged = un
             if obs is not None:
                 obs.event("deadline-cut", bucket=bb, live=live)
-            buf = flush(buf, cur_s, idx, sharded)
+            buf = flush(buf, cur_s, idx)
             break
         nb = pow2_at_least(live)
         if nb <= bb // 2:
             # flush ALL lanes (fixed-length scatter; see compaction._drive),
             # then gather survivors + one inert converged filler lane and
             # re-bucket under the explicit device-put policy.
-            buf = flush(buf, cur_s, idx, sharded)
+            buf = flush(buf, cur_s, idx)
             surv = np.flatnonzero(~conv)
             fill = np.flatnonzero(conv)[:1]
             sel = np.concatenate([surv, np.repeat(fill, nb - live)])
@@ -324,7 +333,7 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
             idx = idx[sel]
             ph_prev = ph[sel]
     else:
-        buf = flush(buf, cur_s, idx, sharded)
+        buf = flush(buf, cur_s, idx)
     return buf
 
 
@@ -404,19 +413,22 @@ def solve_mesh(
     prologue_s, init_s, chunk_s, conv_s, epilogue_s = _mesh_fns(
         spec, mesh, batch_axis, k)
     _, _, chunk_1, conv_1, _ = spec_fns(spec, k)
-    ops = {kk: jax.device_put(jnp.asarray(v), sh)
-           for kk, v in p.ops.items()}
+    # straight onto the mesh; dropping ``p`` frees any staged copy of the
+    # masked operands on the default device once the transfer is done
+    ops = {kk: jax.device_put(v, sh) for kk, v in p.ops.items()}
+    bp, phase_cap = p.bp, p.phase_cap
+    del p
     data, ctx = prologue_s(ops)
     # verbatim epilogue operands come straight from the sharded ops (see
     # compaction.solve_compacting for the second-copy argument)
     ctx = {**ctx, **{kk: ops[kk] for kk in spec.ctx_ops}}
     state0 = init_s(data, ctx)
-    stats = DistributedStats(batch=b, dispatched_batch=p.bp, chunk=k,
+    stats = DistributedStats(batch=b, dispatched_batch=bp, chunk=k,
                              devices=d, batch_axis=batch_axis,
                              placement="batch")
     final = _drive_distributed(
         data, state0, chunk_s, conv_s, chunk_1, conv_1,
-        max_chunk_dispatches(p.phase_cap, k), stats, mesh, batch_axis,
+        max_chunk_dispatches(phase_cap, k), stats, mesh, batch_axis,
         deadline=deadline, obs=obs,
     )
     r = epilogue_s(ctx, final)
@@ -547,7 +559,7 @@ def _trace_mesh_chunk(spec_name: str):
 
     spec = ASSIGNMENT if spec_name == "assignment" else OT
     mesh = make_batch_mesh()
-    _, _, chunk_s, conv_s, _ = _mesh_fns(spec, mesh, "data", 2)
+    _, _, chunk_s, _, _ = _mesh_fns(spec, mesh, "data", 2)
     _, _, data, state = _tiny_batch(spec_name)
     return _audit.trace_entry(
         name=f"core.distributed.mesh_chunk[{spec_name}]",

@@ -39,6 +39,22 @@ def proposal_keys(m: int, n: int, salt: jnp.ndarray) -> jnp.ndarray:
     return _mix(rows * _H1 + cols * _H2 + salt.astype(jnp.uint32) * _H3)
 
 
+def vary_like(tree, *refs):
+    """``tree`` marked varying over every ``shard_map`` axis any of ``refs``
+    varies over; a no-op outside ``shard_map``.
+
+    A ``while_loop`` whose initial carry is built from constants fails the
+    varying-axes check when its body mixes in sharded operands: the carry
+    leaves the body varying but entered it unvarying."""
+    axes = frozenset().union(*(jax.typeof(r).vma for r in refs))
+
+    def cast(x):
+        missing = tuple(sorted(axes - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree.map(cast, tree)
+
+
 class MaximalMatchingState(NamedTuple):
     mprime_b: jnp.ndarray   # (m,) int32: M' partner col per row, -1 if none
     mprime_a: jnp.ndarray   # (n,) int32: M' partner row per col, -1 if none
@@ -73,14 +89,14 @@ def greedy_maximal_matching(
     if propose_fn is None:
         propose_fn = _propose_dense
 
-    init = MaximalMatchingState(
+    init = vary_like(MaximalMatchingState(
         mprime_b=jnp.full((m,), -1, jnp.int32),
         mprime_a=jnp.full((n,), -1, jnp.int32),
         avail_a=jnp.ones((n,), bool),
         active_b=in_bprime,
         rounds=jnp.int32(0),
         done=jnp.bool_(False),
-    )
+    ), c_int, y_b, y_a, in_bprime, salt)
 
     def cond(s: MaximalMatchingState):
         return (~s.done) & (s.rounds < jnp.int32(min(m, n) + 1))

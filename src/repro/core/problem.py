@@ -83,6 +83,19 @@ def pow2_at_least(x: int) -> int:
     return p
 
 
+def lane_map(fn):
+    """``fn`` applied to each lane of its (batched) arguments in turn.
+
+    The float epilogues run through this instead of ``jax.vmap``: XLA
+    compiles a vmapped epilogue differently for different batch widths (on
+    a TPU v5e a one-lane batch orders the plan's float reductions unlike a
+    wider one), so a lane's plan would depend on how many lanes shared its
+    dispatch — a quarantine bisection, for one, re-dispatches survivors in
+    smaller batches. A sequential map compiles one per-lane body for every
+    width."""
+    return lambda *args: jax.lax.map(lambda a: fn(*a), args)
+
+
 def eps_array(eps, b: int, guaranteed: bool) -> np.ndarray:
     """(b,) host-float64 per-instance eps (the /3 of the guaranteed bound
     applied); shared by every driver so the scaling can never diverge."""

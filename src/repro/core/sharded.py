@@ -21,7 +21,6 @@ from .pushrelabel import (
     AssignmentResult, complete_matching, round_costs, solve_assignment_int,
 )
 
-from ..compat import pvary as _pvary, shard_map as _shard_map
 
 
 def solve_assignment_sharded(
@@ -261,7 +260,8 @@ def _phase_shardmap(c_blk, carry, salt0, row_axis, col_axis, m, n,
         active_b = active_b & ~won
         any_prop = jax.lax.pmax(
             jnp.any(prop >= 0).astype(jnp.int32), (row_axis, col_axis))
-        done = _pvary(any_prop == 0, (row_axis, col_axis))
+        done = jax.lax.pcast(any_prop == 0, (row_axis, col_axis),
+                             to="varying")
         return (mprime_b, mprime_a, avail_blk, active_b, rounds + 1, done)
 
     init = (jnp.full((m_loc,), -1) + zero, jnp.full((n_loc,), _BIG32) + zero,
@@ -347,7 +347,7 @@ def solve_assignment_shardmap(
             jax.lax.pmax(rd, (row_axis, col_axis)),
         )
 
-    out = jax.jit(_shard_map(
+    out = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=P(row_axis, col_axis),
         out_specs=(P(row_axis), P(col_axis), P(row_axis), P(col_axis),

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .matching import proposal_keys
+from .matching import proposal_keys, vary_like
 
 
 class OTState(NamedTuple):
@@ -122,14 +122,11 @@ def _phase(c_int, s: OTState, max_rounds: int) -> OTState:
         rem_b = rem_b - grant
         return (rem_b, cap_a, granted, rounds + 1, ~any_prop)
 
-    # Derive loop-carry zeros from data so the carry's varying-axes match
-    # under shard_map (a literal jnp.int32(0) is unvarying and trips the
-    # vma check when the body mixes in sharded data).
-    zero_s = jnp.sum(c_int[:1, :1]) * 0
     rem_b, cap_a, granted, rounds, _ = jax.lax.while_loop(
         cond,
         body,
-        (free_b0, cap0, granted0 + zero_s, zero_s, zero_s != 0),
+        vary_like((free_b0, cap0, granted0, jnp.int32(0), jnp.bool_(False)),
+                  c_int, *s),
     )
 
     g_a = jnp.sum(granted, axis=0)                       # units matched in M'

@@ -27,8 +27,11 @@ def _sqeuclid_tile(x, y, euclid: bool):
     """Shared (BM, D) x (BN, D) -> (BM, BN) tile body."""
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     y2 = jnp.sum(y * y, axis=1, keepdims=True)
+    # full f32 contraction: a single bf16 MXU pass would cost ~1e-2 on
+    # unit-scale points, more than a cost-rounding step at small eps
     g = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     d = jnp.maximum(x2 + y2.T - 2.0 * g, 0.0)
     return jnp.sqrt(d + 1e-30) if euclid else d

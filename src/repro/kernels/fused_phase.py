@@ -44,14 +44,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .slack_propose import _H1, _H2, _H3, _UMAX, _mix, _resolve_interpret
+from .slack_propose import (
+    _H1, _H2, _H3, _I32_MAX, _mix, _resolve_interpret, _signed_keys,
+)
+
+# Scoped VMEM granted to the fused kernels (the compiler's default is
+# 16 MiB); a v5e core has 128 MiB.
+VMEM_LIMIT_BYTES = 100 * 2**20
+
+# Largest padded instance (rows x cols) each kernel holds within
+# VMEM_LIMIT_BYTES, from compiles for a described v5e: OT fits at
+# 1024 x 1024 and not at 1280 x 1280; assignment fits at 2048 x 2048.
+MAX_CELLS = {"assignment": 2048 * 2048, "ot": 1024 * 1024}
+
+
+def check_fits(kind: str, rows: int, cols: int, interpret: bool) -> None:
+    """Raise ``ValueError`` unless a padded ``rows x cols`` instance of
+    ``kind`` fits the fused kernel's VMEM. Only a compiled (Mosaic) kernel
+    has that limit; interpret mode runs at any size. Runs while tracing, so
+    a shape the chip cannot hold is refused before anything is
+    dispatched."""
+    if not interpret and rows * cols > MAX_CELLS[kind]:
+        raise ValueError(
+            f"fused {kind} kernel: a {rows} x {cols} (padded) instance "
+            f"exceeds its VMEM budget of {MAX_CELLS[kind]} cells "
+            f"({VMEM_LIMIT_BYTES >> 20} MiB); solve it with the stepped "
+            "core (DispatchPolicy(fused=False))")
+
 
 # Sentinel cost for tile-padded edges; must match core.pushrelabel.PAD_COST
 # (duals can never sum to it, so padded edges are never admissible).
 _PAD_COST = 1 << 26
-
-_I32_MAX = jnp.iinfo(jnp.int32).max
 
 
 def _iotas(mp: int, np_: int):
@@ -61,22 +86,34 @@ def _iotas(mp: int, np_: int):
 
 
 def _keys(row_u, col_u, salt_round):
-    """uint32 proposal keys, identical to ``matching.proposal_keys``."""
-    return _mix(row_u + col_u + salt_round.astype(jnp.uint32)
-                * jnp.uint32(_H3))
+    """``matching.proposal_keys`` under the order-preserving int32 map, so
+    the min-reductions below pick the same winners."""
+    return _signed_keys(_mix(row_u + col_u + salt_round.astype(jnp.uint32)
+                             * jnp.uint32(_H3)))
 
 
 def _first_min_col(keys, col_i, col_real, np_: int):
     """First column index attaining the row-min key, restricted to logical
     columns — ``jnp.argmin(keys, axis=1)`` re-expressed without gather
-    (padded columns hold UMAX so they never beat a logical min, and the
-    ``col_real`` mask keeps them out of the index min even on all-UMAX
+    (padded columns hold I32_MAX so they never beat a logical min, and the
+    ``col_real`` mask keeps them out of the index min even on all-I32_MAX
     rows, where argmin's first-min falls on column 0)."""
     rowmin = jnp.min(keys, axis=1, keepdims=True)
     return jnp.min(
         jnp.where((keys == rowmin) & col_real, col_i, jnp.int32(np_)),
         axis=1, keepdims=True,
     )
+
+
+def _cumsum_rows(x, row_i):
+    """Inclusive prefix sum along axis 0 (``jnp.cumsum(x, axis=0)``) as a
+    log-step scan of sublane rotations: Mosaic has no cumsum. Integer
+    adds, so the result is exact in any order."""
+    shift = 1
+    while shift < x.shape[0]:
+        x = x + jnp.where(row_i >= shift, pltpu.roll(x, shift, 0), 0)
+        shift *= 2
+    return x
 
 
 # --------------------------------------------------------------------------
@@ -116,13 +153,15 @@ def _assignment_kernel(c_ref, mba_ref, mab_ref, yb_ref, ya_ref, scal_ref,
             _, _, _, r, done = t
             return (~done) & (r < mm_cap)
 
+        # avail / active ride the loop as int32 0/1: Mosaic cannot carry
+        # boolean vectors through a loop
         def mm_body(t):
             mpb, avail, active, r, _ = t
             keys = _keys(row_u, col_u, phases * jnp.int32(7919) + r)
-            adm = (yb + ya == c + 1) & avail
-            keys = jnp.where(adm, keys, jnp.uint32(_UMAX))
+            adm = (yb + ya == c + 1) & (avail != 0)
+            keys = jnp.where(adm, keys, jnp.int32(_I32_MAX))
             best = _first_min_col(keys, col_i, col_real, np_)
-            has_prop = jnp.any(adm, axis=1, keepdims=True) & active
+            has_prop = jnp.any(adm, axis=1, keepdims=True) & (active != 0)
             prop = has_prop & (best == col_i)             # one-hot proposals
             # accept: per column, lowest-index proposing row wins
             winners = jnp.min(jnp.where(prop, row_i, jnp.int32(mp)),
@@ -130,13 +169,13 @@ def _assignment_kernel(c_ref, mba_ref, mab_ref, yb_ref, ya_ref, scal_ref,
             won_edge = prop & (winners == row_i)
             won = jnp.any(won_edge, axis=1, keepdims=True)
             taken = jnp.any(won_edge, axis=0, keepdims=True)
-            return (jnp.where(won, best, mpb), avail & ~taken,
-                    active & ~won, r + 1, ~jnp.any(has_prop))
+            return (jnp.where(won, best, mpb), jnp.where(taken, 0, avail),
+                    jnp.where(won, 0, active), r + 1, ~jnp.any(has_prop))
 
         mpb, _, _, mm_rounds, _ = jax.lax.while_loop(
             mm_cond, mm_body,
-            (jnp.full((mp, 1), -1, jnp.int32), col_real, in_bp,
-             jnp.int32(0), jnp.bool_(False)),
+            (jnp.full((mp, 1), -1, jnp.int32), col_real.astype(jnp.int32),
+             in_bp.astype(jnp.int32), jnp.int32(0), jnp.bool_(False)),
         )
 
         # (II) push: add M' to M, displacing old partners of M' columns
@@ -193,6 +232,8 @@ def fused_assignment_phases(
     m, n = c_int.shape
     mp = m + (-m) % block_m
     np_ = n + (-n) % block_n
+    interpret = _resolve_interpret(interpret)
+    check_fits("assignment", mp, np_, interpret)
     c_p = _pad2(c_int, mp, np_, _PAD_COST)
     mba_p = jnp.pad(match_ba, (0, mp - m),
                     constant_values=-1).reshape(mp, 1)
@@ -216,7 +257,9 @@ def fused_assignment_phases(
             jax.ShapeDtypeStruct((1, np_), i32),
             jax.ShapeDtypeStruct((1, 8), i32),
         ],
-        interpret=_resolve_interpret(interpret),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(c_p, mba_p, mab_p, yb_p, ya_p, scal)
     return (mba[:m, 0], mab[0, :n], yb[:m, 0], ya[0, :n],
             scal[0, 0], scal[0, 1], scal[0, 2])
@@ -263,14 +306,14 @@ def _ot_kernel(c_ref, yb_ref, yahi_ref, fb_ref, fa_ref, fhi_ref, flo_ref,
             rem, cap, granted, r, _ = t
             keys = _keys(row_u, col_u, phases * jnp.int32(7919) + r)
             adm = (yb + yahi == c + 1) & (cap > 0)
-            keys = jnp.where(adm, keys, jnp.uint32(_UMAX))
+            keys = jnp.where(adm, keys, jnp.int32(_I32_MAX))
             best = _first_min_col(keys, col_i, col_real, nap)
             can = jnp.any(adm, axis=1, keepdims=True) & (rem > 0)
             prop = can & (best == col_i)                # one-hot proposals
             # FIFO grants by row order: segmented exclusive prefix of the
             # proposal amounts (transport._grant_round), one-hot reduced
             amt = jnp.where(can, rem, 0)
-            excl = jnp.cumsum(amt, axis=0) - amt        # (nbp, 1)
+            excl = _cumsum_rows(amt, row_i) - amt       # (nbp, 1)
             base = jnp.min(
                 jnp.where(prop, jnp.broadcast_to(excl, (nbp, nap)), big),
                 axis=0, keepdims=True)                  # per-col min excl
@@ -297,7 +340,7 @@ def _ot_kernel(c_ref, yb_ref, yahi_ref, fb_ref, fa_ref, fhi_ref, flo_ref,
         disp = g_a - use_free
         # suffix-exclusive column sums == reversed-cumsum form, exactly
         suffix_excl = (jnp.sum(fhi, axis=0, keepdims=True)
-                       - jnp.cumsum(fhi, axis=0))
+                       - _cumsum_rows(fhi, row_i))
         take = jnp.clip(disp - suffix_excl, 0, fhi)
         fhi2 = fhi - take
         freed = jnp.sum(take, axis=1, keepdims=True)
@@ -342,6 +385,8 @@ def fused_ot_phases(
     nb, na = c_int.shape
     nbp = nb + (-nb) % block_m
     nap = na + (-na) % block_n
+    interpret = _resolve_interpret(interpret)
+    check_fits("ot", nbp, nap, interpret)
     c_p = _pad2(c_int, nbp, nap, _PAD_COST)
     yb_p = jnp.pad(y_b, (0, nbp - nb)).reshape(nbp, 1)
     fb_p = jnp.pad(free_b, (0, nbp - nb)).reshape(nbp, 1)
@@ -366,7 +411,9 @@ def fused_ot_phases(
             jax.ShapeDtypeStruct((nbp, nap), i32),
             jax.ShapeDtypeStruct((1, 8), i32),
         ],
-        interpret=_resolve_interpret(interpret),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(c_p, yb_p, yahi_p, fb_p, fa_p, fhi_p, flo_p, scal)
     return (yb[:nb, 0], yahi[0, :na], fb[:nb, 0], fa[0, :na],
             fhi[:nb, :na], flo[:nb, :na], scal[0, 0], scal[0, 1])

@@ -22,6 +22,8 @@ _H1 = 2654435761
 _H2 = 2246822519
 _H3 = 3266489917
 _UMAX = 0xFFFFFFFF
+_SIGN = 0x80000000
+_I32_MAX = 0x7FFFFFFF   # == _signed_keys(_UMAX)
 
 
 def _mix(h):
@@ -34,25 +36,41 @@ def _mix(h):
     return h ^ (h >> jnp.uint32(16))
 
 
+def _signed_keys(h):
+    """Order-preserving uint32 -> int32 map: flip the sign bit, then
+    bitcast. Mosaic has no min-reduction over unsigned integers, so every
+    key reduction runs on these; ``_UMAX`` maps to ``_I32_MAX``."""
+    return jax.lax.bitcast_convert_type(h ^ jnp.uint32(_SIGN), jnp.int32)
+
+
+def _unsigned_keys(k):
+    """Inverse of ``_signed_keys``."""
+    return jax.lax.bitcast_convert_type(k, jnp.uint32) ^ jnp.uint32(_SIGN)
+
+
 def _tile_propose(c, yb, ya, avail, salt, i, j, bm: int, bn: int):
     """Shared tile body: fused slack + admissibility + hash-key argmin on
     one (bm, bn) tile at grid position (i, j). Returns the tile's winning
-    (key, global col) per row, each (bm, 1). Both the unbatched and the
-    batched kernel reduce these with the identical first-min accumulator,
-    so the two stay bit-identical by construction."""
+    (signed key, global col) per row, each (bm, 1). Both the unbatched and
+    the batched kernel reduce these with the identical first-min
+    accumulator, so the two stay bit-identical by construction."""
     rows_g = (i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0)
               ).astype(jnp.uint32)
     cols_l = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
     cols_g = (j * bn + cols_l).astype(jnp.uint32)
 
-    keys = _mix(rows_g * jnp.uint32(_H1) + cols_g * jnp.uint32(_H2)
-                + salt * jnp.uint32(_H3))
+    keys = _signed_keys(_mix(rows_g * jnp.uint32(_H1)
+                             + cols_g * jnp.uint32(_H2)
+                             + salt * jnp.uint32(_H3)))
     adm = (yb + ya == c + 1) & (avail != 0)
-    keys = jnp.where(adm, keys, jnp.uint32(_UMAX))
+    keys = jnp.where(adm, keys, jnp.int32(_I32_MAX))
 
+    # argmin's first-min as two min-reductions (Mosaic's argmin takes
+    # float32 only)
     tile_key = jnp.min(keys, axis=1, keepdims=True)          # (bm, 1)
-    tile_col = (j * bn + jnp.argmin(keys, axis=1)[:, None]).astype(jnp.int32)
-    return tile_key, tile_col
+    first = jnp.min(jnp.where(keys == tile_key, cols_l, jnp.int32(bn)),
+                    axis=1, keepdims=True)
+    return tile_key, j * bn + first
 
 
 def _kernel(salt_ref, c_ref, yb_ref, ya_ref, avail_ref, col_out, key_out,
@@ -67,7 +85,7 @@ def _kernel(salt_ref, c_ref, yb_ref, ya_ref, avail_ref, col_out, key_out,
 
     @pl.when(j == 0)
     def _init():
-        key_out[...] = jnp.full_like(key_out[...], jnp.uint32(_UMAX))
+        key_out[...] = jnp.full_like(key_out[...], jnp.int32(_I32_MAX))
         col_out[...] = jnp.full_like(col_out[...], -1)
 
     better = tile_key < key_out[...]
@@ -124,11 +142,11 @@ def slack_propose(
         ],
         out_shape=[
             jax.ShapeDtypeStruct((mp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((mp, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.int32),
         ],
         interpret=interpret,
     )(salt_arr, c_p, yb_p, ya_p, av_p)
-    return col[:m, 0], key[:m, 0]
+    return col[:m, 0], _unsigned_keys(key[:m, 0])
 
 
 def _kernel_batched(salt_ref, c_ref, yb_ref, ya_ref, avail_ref,
@@ -146,7 +164,7 @@ def _kernel_batched(salt_ref, c_ref, yb_ref, ya_ref, avail_ref,
 
     @pl.when(j == 0)
     def _init():
-        key_out[...] = jnp.full_like(key_out[...], jnp.uint32(_UMAX))
+        key_out[...] = jnp.full_like(key_out[...], jnp.int32(_I32_MAX))
         col_out[...] = jnp.full_like(col_out[...], -1)
 
     better = tile_key[None] < key_out[...]
@@ -199,8 +217,8 @@ def slack_propose_batched(
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, mp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((b, mp, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((b, mp, 1), jnp.int32),
         ],
         interpret=interpret,
     )(salt_arr, c_p, yb_p, ya_p, av_p)
-    return col[:, :m, 0], key[:, :m, 0]
+    return col[:, :m, 0], _unsigned_keys(key[:, :m, 0])

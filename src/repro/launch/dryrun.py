@@ -190,9 +190,7 @@ def run_cell(arch, shape_name, *, multi_pod=False, router=None, small=False,
             return {"arch": arch, "shape": shape_name,
                     "multi_pod": multi_pod, "ok": True, **meta}
         compiled = lowered.compile()
-        from repro.compat import cost_analysis_dict
-
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         ma = compiled.memory_analysis()
         hlo = compiled.as_text()
         tp_size = meta["mesh"].get("model", 16)

@@ -1,24 +1,16 @@
 """Production mesh builders. FUNCTIONS (not module constants) so importing
 never touches jax device state.
-
-``_make_mesh`` papers over jax API drift: ``axis_types=`` (and
-``jax.sharding.AxisType``) only exist on newer jax; older releases build
-the same Auto-axis mesh without the kwarg.
 """
 from __future__ import annotations
 
-import inspect
 import math
 
 import jax
 
 
 def _make_mesh(shape, axes, devices):
-    kwargs = {}
-    if (hasattr(jax.sharding, "AxisType")
-            and "axis_types" in inspect.signature(jax.make_mesh).parameters):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devices, **kwargs)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

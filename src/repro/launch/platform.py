@@ -1,16 +1,17 @@
-"""Backend selection + per-backend XLA tuning flags, applied BEFORE the
-first jax device touch.
+"""Backend selection, per-backend XLA tuning flags and the persistent
+compilation cache, applied BEFORE the first jax device touch.
 
 ``set_platform`` pins ``jax_platform_name`` and, for GPU, installs the
 latency-hiding / async-stream XLA flags the fused phase kernels are
 tuned against (the paper's GPU implementation overlaps the propose/push
 sweeps with collective traffic; XLA only does the equivalent when the
 latency-hiding scheduler and high-priority async streams are enabled).
+``use_compile_cache`` places JAX's persistent compilation cache.
 Like the mesh builders in ``launch/mesh.py``, everything here is a
 FUNCTION — importing this module never touches jax backend state, and
-``set_platform`` must run before the first computation (jax initializes
-its backend once, on first use; ``jax.config.update`` after that point
-is silently ignored for an already-initialized backend).
+these must run before the first computation (jax initializes its backend
+once, on first use; ``jax.config.update`` after that point is silently
+ignored for an already-initialized backend).
 
 The flag set mirrors jax's own GPU performance guidance; `gpu_flags()`
 exposes it separately so launchers that manage ``XLA_FLAGS`` themselves
@@ -19,6 +20,7 @@ exposes it separately so launchers that manage ``XLA_FLAGS`` themselves
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
 import jax
 
@@ -31,6 +33,9 @@ _GPU_XLA_FLAGS = (
 )
 
 _PLATFORMS = ("cpu", "gpu", "tpu")
+
+# <checkout>/.jax_cache: src/repro/launch/platform.py is three levels down
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def gpu_flags() -> str:
@@ -58,3 +63,17 @@ def set_platform(platform: str = "cpu") -> None:
                 if f.split("=")[0] not in existing]
         os.environ["XLA_FLAGS"] = " ".join(
             ([existing] if existing else []) + keep)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    nothing here sets another directory. Otherwise the cache lives at the
+    fixed :data:`DEFAULT_CACHE_DIR` inside the checkout — a fixed path,
+    because the directory is part of what a later process must find."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
+    return str(DEFAULT_CACHE_DIR)

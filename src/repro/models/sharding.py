@@ -12,9 +12,6 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map  # noqa: F401  (models call
-# sharding.shard_map; the version-drift handling lives in repro.compat)
-
 _STATE = {"mesh": None, "dp": ("pod", "data"), "tp": "model"}
 
 

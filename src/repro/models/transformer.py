@@ -124,7 +124,7 @@ def apply_moe(p, cfg, x):
             "w_up": P(tp, None, None),
             "w_down": P(tp, None, None),
         }
-        out = sharding.shard_map(
+        out = jax.shard_map(
             lambda xx, pp: moe_forward(
                 pp, cfg.with_(num_shared_experts=0), xx, axis_name=tp
             ),

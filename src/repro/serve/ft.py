@@ -25,6 +25,7 @@ import time
 from typing import Any, Callable, List, Optional, Tuple
 
 import jax
+from jax.experimental import checkify
 
 from ..core.validate import RequestRejected  # noqa: F401  (re-export: the
 #   serving layers raise it for both admission and dispatch-time poison)
@@ -59,14 +60,13 @@ def require_mass_pair(nu, mu, *, who: str = "request") -> bool:
 
 def is_transient(exc: BaseException) -> bool:
     """Worth retrying? Injected :class:`TransientDispatchError`, plus
-    device-runtime failures (``XlaRuntimeError``: OOM, collective errors,
-    backend faults) — those are attempt properties, not data properties,
-    and a smaller/safer rung may succeed."""
+    device-runtime failures (``jax.errors.JaxRuntimeError``: OOM,
+    collective errors, backend faults) — those are attempt properties, not
+    data properties, and a smaller/safer rung may succeed. Poison (see
+    :func:`is_poison`) is never transient."""
     if isinstance(exc, TransientDispatchError):
         return True
-    # jaxlib's XlaRuntimeError moves between modules across jax versions;
-    # match by name so the ladder doesn't couple to a private import path
-    return type(exc).__name__ == "XlaRuntimeError"
+    return isinstance(exc, jax.errors.JaxRuntimeError) and not is_poison(exc)
 
 
 def is_poison(exc: BaseException) -> bool:
@@ -79,11 +79,7 @@ def is_poison(exc: BaseException) -> bool:
         return True
     if getattr(exc, "poisoned_instance", False):
         return True
-    try:
-        from jax.experimental.checkify import JaxRuntimeError
-    except ImportError:                       # pragma: no cover
-        return False
-    return isinstance(exc, JaxRuntimeError)
+    return isinstance(exc, checkify.JaxRuntimeError)
 
 
 def degradation_ladder(policy) -> List[Tuple[str, Any, Any]]:

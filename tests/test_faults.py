@@ -135,6 +135,36 @@ def test_failure_taxonomy():
     assert not is_poison(ValueError("x"))
 
 
+def test_device_runtime_error_is_transient():
+    """A real device failure (here an out-of-memory allocation) arrives as
+    ``jax.errors.JaxRuntimeError`` and must walk the ladder."""
+    import jax
+    import jax.numpy as jnp
+
+    with pytest.raises(jax.errors.JaxRuntimeError) as info:
+        jax.jit(lambda: jnp.zeros((2**20, 2**20, 2**10), jnp.float32))()
+    assert "RESOURCE_EXHAUSTED" in str(info.value)
+    assert is_transient(info.value)
+    assert not is_poison(info.value)
+
+
+def test_checkify_runtime_error_stays_poison():
+    """checkify's ``JaxRuntimeError`` is a property of the data: poison,
+    never retried as transient."""
+    import jax.numpy as jnp
+    from jax.experimental import checkify
+
+    def f(x):
+        checkify.check(jnp.all(x > 0), "non-positive input")
+        return x
+
+    err, _ = checkify.checkify(f)(jnp.float32(-1.0))
+    with pytest.raises(checkify.JaxRuntimeError) as info:
+        err.throw()
+    assert is_poison(info.value)
+    assert not is_transient(info.value)
+
+
 def test_run_with_recovery_walks_ladder_and_backoff():
     ladder = [("mesh", "P0", None), ("compact", "P1", None),
               ("cpu", "P2", "dev")]

@@ -87,8 +87,7 @@ hlo = compiled.as_text()
 out["has_collectives"] = any(
     op in hlo for op in ("all-reduce", "all-gather", "collective-permute")
 )
-from repro.compat import cost_analysis_dict
-out["flops"] = cost_analysis_dict(compiled).get("flops", 0)
+out["flops"] = compiled.cost_analysis().get("flops", 0)
 print("RESULT:" + json.dumps(out))
 """
 

@@ -427,7 +427,7 @@ def main():
     #             --calibrate --json mymodel.json
     #     then repro.portfolio.set_model(CostModel.load("mymodel.json")).
     #     The chosen solver and predicted-vs-actual seconds land in
-    #     stats and in the "solver-choice" obs event.
+    #     stats.
     pol_auto = DispatchPolicy(mode="compact", solver="auto")
     sols_a = solve(OT, batch16, 0.1, pol_auto, want=("cost", "stats"))
     model = get_model()

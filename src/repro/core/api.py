@@ -47,6 +47,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from ..obs.metrics import now as _now
+from ..obs.tracing import region
 from .compaction import DEFAULT_CHUNK, CompactionStats, solve_compacting
 from .distributed import solve_mesh
 from .problem import (  # noqa: F401  (re-exported: the front door and
@@ -177,8 +178,8 @@ def _resolve_solver(spec, policy: DispatchPolicy, inputs, eps):
     from .. import portfolio
 
     solver = policy.solver
-    c = np.asarray(inputs["c"]) if isinstance(inputs, dict) else None
-    n_eff = int(max(c.shape[1], c.shape[2])) if c is not None else 0
+    shape = np.shape(inputs["c"]) if isinstance(inputs, dict) else None
+    n_eff = int(max(shape[1], shape[2])) if shape is not None else 0
     eps_min = float(np.min(np.asarray(eps, np.float64)))
     if solver == "auto":
         solver, predicted = portfolio.choose(n_eff, eps_min)
@@ -217,16 +218,19 @@ def dispatch(
     the chunked drivers (best-so-far cut; lockstep has no chunk loop to
     cut, so the combination raises). ``obs`` threads a per-chunk event
     emitter (``repro.obs.Tracer``) into the chunked drivers; lockstep
-    ignores it (one unbounded program, nothing per-chunk to report).
+    ignores it (one unbounded program, nothing per-chunk to report). The
+    host prep (solver routing, the admission check, then each driver's
+    masking and upload) is marked as ``solve.prepare`` regions
+    (``repro.obs.region``).
 
     ``policy.solver`` routes the bucket through the solver portfolio
     (push-relabel / Sinkhorn / hybrid / measured-auto); the chosen
     solver, the cost model's prediction, and the measured dispatch wall
     time are annotated onto the returned stats (``solver`` /
-    ``predicted_s`` / ``solve_s``) and emitted as a ``"solver-choice"``
-    obs event."""
+    ``predicted_s`` / ``solve_s``)."""
     policy = policy or DispatchPolicy()
-    solver, spec, predicted = _resolve_solver(spec, policy, inputs, eps)
+    with region(obs, "solve.prepare"):
+        solver, spec, predicted = _resolve_solver(spec, policy, inputs, eps)
     t0 = _now()
     if solver == "hybrid":
         from ..portfolio.hybrid import dispatch_hybrid
@@ -248,9 +252,6 @@ def dispatch(
                 setattr(stats, kk, v)
             except (AttributeError, TypeError):
                 pass
-    if obs is not None:
-        obs.event("solver-choice", solver=solver, predicted_s=predicted,
-                  solve_s=solve_s)
     return r, stats
 
 
@@ -274,7 +275,8 @@ def _dispatch_one(
         spec = fused_variant(spec)
     if policy.validate:
         from .validate import check_admission
-        check_admission(spec.canonicalize(inputs), sizes=sizes)
+        with region(obs, "solve.prepare"):
+            check_admission(spec.canonicalize(inputs), sizes=sizes)
     if mode == "lockstep":
         if deadline is not None:
             raise ValueError(

@@ -54,6 +54,7 @@ import numpy as np
 # timing and deadline checks here share a time base with the scheduler's
 # spans, submit timestamps, and per-request deadlines
 from ..obs.metrics import now as _now
+from ..obs.tracing import region
 from .problem import ASSIGNMENT, OT, lane_map, pow2_at_least
 
 DEFAULT_CHUNK = 8
@@ -141,9 +142,12 @@ def _drive(data, state, run_fn, conv_fn, max_chunks: int,
     the batch bucket, live-lane count, wall time, max phase delta, and
     the chunk program's jit-cache delta (nonzero exactly when this
     dispatch compiled), plus a ``"deadline-cut"`` event when the budget
-    stops the loop. Everything emitted is a host scalar the loop already
-    had — observability adds no device->host syncs (the sync audit holds
-    this loop to the single conv fetch either way)."""
+    stops the loop. Each chunk, launch through the conv fetch, is also a
+    ``solve.chunk`` region on the profiler's host timeline
+    (``repro.obs.region``; the ``"chunk"`` event is its record for the
+    sinks). Everything emitted is a host scalar the loop already had —
+    observability adds no device->host syncs (the sync audit holds this
+    loop to the single conv fetch either way)."""
     idx = np.arange(stats.dispatched_batch)
     cache_fn = getattr(run_fn, "_cache_size", None) if obs is not None \
         else None
@@ -156,11 +160,12 @@ def _drive(data, state, run_fn, conv_fn, max_chunks: int,
     cur_d, cur_s = data, state
     ph_prev = np.zeros((stats.dispatched_batch,), np.int64)
     for _ in range(max_chunks):
-        t_chunk = _now()
-        cur_s = run_fn(cur_d, cur_s)
-        stats.dispatches += 1
-        conv, ph = jax.device_get(conv_fn(cur_d, cur_s))
-        t_chunk = _now() - t_chunk
+        with region(obs, "solve.chunk", record=False):
+            t_chunk = _now()
+            cur_s = run_fn(cur_d, cur_s)
+            stats.dispatches += 1
+            conv, ph = jax.device_get(conv_fn(cur_d, cur_s))
+            t_chunk = _now() - t_chunk
         ph = ph.astype(np.int64)
         bb = int(conv.shape[0])
         # the vmapped while_loop runs every lane for the max phase delta
@@ -228,27 +233,46 @@ def _drive(data, state, run_fn, conv_fn, max_chunks: int,
 # single-device tail of the distributed driver.
 # --------------------------------------------------------------------------
 
+STAGES = ("prologue", "init", "chunk", "conv", "epilogue")
+
+
+def stage_fns(spec, k: int, prefix: str = ""):
+    """The spec's five per-solve functions (:data:`STAGES`) vmapped (the
+    float epilogue lane-mapped, ``problem.lane_map``) over a batch, each
+    named ``<spec.name>_<prefix><stage>``: jitted, a program is then the
+    module ``jit_ot_chunk`` (``jit_ot_mesh_chunk`` for the mesh's
+    ``prefix="mesh_"``) in an HLO dump and a device trace, where an
+    anonymous lambda would be ``jit__lambda``. ``conv`` returns ``(mask,
+    phases)`` in one program so the driver's per-chunk device->host sync
+    fetches both in a single blocking transfer (the hot-loop sync audit
+    in repro.analysis holds the loop to exactly that one fetch)."""
+    def prologue(ops):
+        return jax.vmap(spec.prologue)(ops)
+
+    def init(data, ctx):
+        return jax.vmap(spec.init_state)(data, ctx)
+
+    def chunk(data, state):
+        return jax.vmap(lambda d, s: spec.run_phases(d, s, k))(data, state)
+
+    def conv(data, state):
+        return jax.vmap(spec.converged)(data, state), state.phases
+
+    fns = (prologue, init, chunk, conv, lane_map(spec.epilogue))
+    for stage, fn in zip(STAGES, fns):
+        fn.__name__ = fn.__qualname__ = f"{spec.name}_{prefix}{stage}"
+    return fns
+
+
 @lru_cache(maxsize=None)
 def spec_fns(spec, k: int):
-    """(prologue, init, chunk, conv, epilogue): the spec's per-instance
-    stepped-core functions vmapped over the batch and jitted. The chunk
-    dispatch donates the state buffers (one copy of solver state on
-    device, not two). ``conv`` returns ``(mask, phases)`` in one program
-    so the driver's per-chunk device->host sync fetches both in a single
-    blocking transfer (the hot-loop sync audit in repro.analysis holds
-    the loop to exactly that one fetch)."""
-    prologue = jax.jit(lambda ops: jax.vmap(spec.prologue)(ops))
-    init = jax.jit(lambda data, ctx: jax.vmap(spec.init_state)(data, ctx))
-    chunk = jax.jit(
-        lambda data, state: jax.vmap(
-            lambda d, s: spec.run_phases(d, s, k))(data, state),
-        donate_argnums=(1,),
-    )
-    conv = jax.jit(
-        lambda data, state: (jax.vmap(spec.converged)(data, state),
-                             state.phases))
-    epilogue = jax.jit(lane_map(spec.epilogue))
-    return prologue, init, chunk, conv, epilogue
+    """(prologue, init, chunk, conv, epilogue): :func:`stage_fns` jitted.
+    The chunk dispatch donates the state buffers (one copy of solver
+    state on device, not two)."""
+    prologue, init, chunk, conv, epilogue = stage_fns(spec, k)
+    return (jax.jit(prologue), jax.jit(init),
+            jax.jit(chunk, donate_argnums=(1,)), jax.jit(conv),
+            jax.jit(epilogue))
 
 
 def max_chunk_dispatches(phase_cap: np.ndarray, k: int) -> int:
@@ -288,31 +312,37 @@ def solve_compacting(
         ``unconverged``).
       obs: optional event emitter (``repro.obs.Tracer``): per-chunk
         ``"chunk"`` events (bucket, live, wall time, phase delta,
-        jit-cache delta) and ``"deadline-cut"`` — see :func:`_drive`.
+        jit-cache delta) and ``"deadline-cut"`` — see :func:`_drive` —
+        and a ``solve.prepare`` region (``repro.obs.region``) over the
+        host prep up to the prologue launch.
       prep_kw: spec-specific prep options (OT: ``theta``).
 
     Returns ``(result, CompactionStats)``; every result leaf is
     bit-identical per instance to the lockstep path (and to the unbatched
     solver) for a shared scalar eps.
     """
-    inputs = spec.canonicalize(inputs)
-    b, m, n = spec.batch_shape(inputs)
-    if b == 0:
-        return (spec.empty_result(m, n),
-                CompactionStats(batch=0, dispatched_batch=0, chunk=k))
-    # Pad the batch to a power of two with born-converged empty instances,
-    # so the descent B -> B/2 -> ... visits only power-of-two shapes.
-    p = spec.prepare(inputs, eps, sizes=sizes, guaranteed=guaranteed,
-                     **prep_kw)
-    if _audit_debug_checks():
-        # Sanitizer mode: checkify-instrumented (nan/index/div + solver
-        # invariants) variants of the dispatched programs. Slower (no
-        # donation, per-chunk error sync) — never on by default.
-        from ..analysis.checkified import checkified_spec_fns
-        prologue, init, chunk, conv, epilogue = checkified_spec_fns(spec, k)
-    else:
-        prologue, init, chunk, conv, epilogue = spec_fns(spec, k)
-    ops = {kk: jnp.asarray(v) for kk, v in p.ops.items()}
+    with region(obs, "solve.prepare"):
+        inputs = spec.canonicalize(inputs)
+        b, m, n = spec.batch_shape(inputs)
+        if b == 0:
+            return (spec.empty_result(m, n),
+                    CompactionStats(batch=0, dispatched_batch=0, chunk=k))
+        # Pad the batch to a power of two with born-converged empty
+        # instances, so the descent B -> B/2 -> ... visits only
+        # power-of-two shapes.
+        p = spec.prepare(inputs, eps, sizes=sizes, guaranteed=guaranteed,
+                         **prep_kw)
+        if _audit_debug_checks():
+            # Sanitizer mode: checkify-instrumented (nan/index/div +
+            # solver invariants) variants of the dispatched programs.
+            # Slower (no donation, per-chunk error sync) — never on by
+            # default.
+            from ..analysis.checkified import checkified_spec_fns
+            prologue, init, chunk, conv, epilogue = checkified_spec_fns(
+                spec, k)
+        else:
+            prologue, init, chunk, conv, epilogue = spec_fns(spec, k)
+        ops = {kk: jnp.asarray(v) for kk, v in p.ops.items()}
     data, ctx = prologue(ops)
     # epilogue operands the prologue does not transform are taken straight
     # from ops (outside the jit), not round-tripped through it — a

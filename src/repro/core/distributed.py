@@ -77,16 +77,17 @@ from .compaction import (
     max_chunk_dispatches,
     solve_compacting,
     spec_fns,
+    stage_fns,
 )
 from .problem import (
     ASSIGNMENT,
     OT,
     _sizes_arrays,
     eps_array,
-    lane_map,
     pow2_at_least,
 )
 from ..obs.metrics import now as _now
+from ..obs.tracing import region
 
 
 @dataclass
@@ -170,25 +171,16 @@ def _wrap(mesh: Mesh, axis: str, fn, donate=()):
 @lru_cache(maxsize=None)
 def _mesh_fns(spec, mesh: Mesh, axis: str, k: int):
     """(prologue, init, chunk, conv, epilogue): the spec's per-instance
-    stepped-core functions vmapped over the local batch shard and
+    stepped-core functions (``compaction.stage_fns``, named
+    ``<spec.name>_mesh_<stage>``) vmapped over the local batch shard and
     shard_map'ed over the mesh. Every operand/result is placed
     ``NamedSharding(P(axis))``; the chunk dispatch donates the state."""
-    prologue = _wrap(mesh, axis, lambda ops: jax.vmap(spec.prologue)(ops))
-    init = jax.jit(
-        lambda data, ctx: jax.vmap(spec.init_state)(data, ctx),
-        out_shardings=NamedSharding(mesh, P(axis)),
-    )
-    chunk = _wrap(
-        mesh, axis,
-        lambda data, state: jax.vmap(
-            lambda d, s: spec.run_phases(d, s, k))(data, state),
-        donate=(1,),
-    )
-    conv = _wrap(mesh, axis,
-                 lambda data, state: (jax.vmap(spec.converged)(data, state),
-                                      state.phases))
-    epilogue = _wrap(mesh, axis, lane_map(spec.epilogue))
-    return prologue, init, chunk, conv, epilogue
+    prologue, init, chunk, conv, epilogue = stage_fns(spec, k, "mesh_")
+    return (_wrap(mesh, axis, prologue),
+            jax.jit(init, out_shardings=NamedSharding(mesh, P(axis))),
+            _wrap(mesh, axis, chunk, donate=(1,)),
+            _wrap(mesh, axis, conv),
+            _wrap(mesh, axis, epilogue))
 
 
 @lru_cache(maxsize=None)
@@ -232,7 +224,8 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
     the same best-so-far cut semantics as compaction._drive. ``obs`` is
     the same optional per-chunk event emitter as compaction._drive (the
     ``"chunk"`` events additionally carry the device count this dispatch
-    ran on); events are host scalars only — no extra device syncs."""
+    ran on, and each chunk is a ``solve.chunk`` region); events are host
+    scalars only — no extra device syncs."""
     d0 = int(mesh.shape[axis])
     cache_fns = ({id(run_s): getattr(run_s, "_cache_size", None),
                   id(run_1): getattr(run_1, "_cache_size", None)}
@@ -261,17 +254,18 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
 
     ph_prev = np.zeros((stats.dispatched_batch,), np.int64)
     for _ in range(max_chunks):
-        t_chunk = _now()
-        run_fn = run_s if sharded else run_1
-        cur_s = run_fn(cur_d, cur_s)
-        stats.dispatches += 1
-        # global converged-mask + phase-counter gather: ONE (B,)
-        # device->host sync per chunk (conv bundles both outputs, so the
-        # phase counters don't cost a second blocking fetch — the
-        # repro.analysis hot-loop sync audit pins this)
-        conv, ph = jax.device_get((conv_s if sharded else conv_1)(cur_d,
-                                                                  cur_s))
-        t_chunk = _now() - t_chunk
+        with region(obs, "solve.chunk", record=False):
+            t_chunk = _now()
+            run_fn = run_s if sharded else run_1
+            cur_s = run_fn(cur_d, cur_s)
+            stats.dispatches += 1
+            # global converged-mask + phase-counter gather: ONE (B,)
+            # device->host sync per chunk (conv bundles both outputs, so
+            # the phase counters don't cost a second blocking fetch — the
+            # repro.analysis hot-loop sync audit pins this)
+            conv, ph = jax.device_get((conv_s if sharded else conv_1)(
+                cur_d, cur_s))
+            t_chunk = _now() - t_chunk
         ph = ph.astype(np.int64)
         bb = int(conv.shape[0])
         d_now = d0 if sharded else 1
@@ -379,15 +373,17 @@ def solve_mesh(
     loop a wall-clock budget with best-so-far cut semantics (see
     ``solve_compacting``); matrix placement solves instance-by-instance
     with no chunk loop to cut, so it ignores the budget (best-effort).
-    ``obs`` threads a per-chunk event emitter into the drive (see
-    ``solve_compacting``); matrix placement emits nothing.
+    ``obs`` threads a per-chunk event emitter into the drive and marks
+    the host prep as ``solve.prepare`` regions (see
+    ``solve_compacting``); matrix placement emits no chunk events.
 
     Returns ``(result, DistributedStats)``."""
-    inputs = spec.canonicalize(inputs)
-    b, m, n = spec.batch_shape(inputs)
-    mesh, d = _resolve_mesh(mesh, batch_axis)
-    mode = (choose_placement(b, m, n, d) if placement == "auto"
-            else placement)
+    with region(obs, "solve.prepare"):
+        inputs = spec.canonicalize(inputs)
+        b, m, n = spec.batch_shape(inputs)
+        mesh, d = _resolve_mesh(mesh, batch_axis)
+        mode = (choose_placement(b, m, n, d) if placement == "auto"
+                else placement)
     if mode == "matrix" and b > 0:
         if keep_state and not getattr(spec, "state_on_result", False):
             # the matrix path discards the per-instance integer state
@@ -407,17 +403,19 @@ def solve_mesh(
                             dispatched_batch or None)
         return out, stats
 
-    p = spec.prepare(inputs, eps, sizes=sizes, guaranteed=guaranteed,
-                     min_batch=d, **prep_kw)
-    sh = NamedSharding(mesh, P(batch_axis))
-    prologue_s, init_s, chunk_s, conv_s, epilogue_s = _mesh_fns(
-        spec, mesh, batch_axis, k)
-    _, _, chunk_1, conv_1, _ = spec_fns(spec, k)
-    # straight onto the mesh; dropping ``p`` frees any staged copy of the
-    # masked operands on the default device once the transfer is done
-    ops = {kk: jax.device_put(v, sh) for kk, v in p.ops.items()}
-    bp, phase_cap = p.bp, p.phase_cap
-    del p
+    with region(obs, "solve.prepare"):
+        p = spec.prepare(inputs, eps, sizes=sizes, guaranteed=guaranteed,
+                         min_batch=d, **prep_kw)
+        sh = NamedSharding(mesh, P(batch_axis))
+        prologue_s, init_s, chunk_s, conv_s, epilogue_s = _mesh_fns(
+            spec, mesh, batch_axis, k)
+        _, _, chunk_1, conv_1, _ = spec_fns(spec, k)
+        # straight onto the mesh; dropping ``p`` frees any staged copy of
+        # the masked operands on the default device once the transfer is
+        # done
+        ops = {kk: jax.device_put(v, sh) for kk, v in p.ops.items()}
+        bp, phase_cap = p.bp, p.phase_cap
+        del p
     data, ctx = prologue_s(ops)
     # verbatim epilogue operands come straight from the sharded ops (see
     # compaction.solve_compacting for the second-copy argument)

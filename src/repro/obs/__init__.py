@@ -10,7 +10,8 @@ Three pieces, all import-light (stdlib only at import time):
   clock with per-request trace ids, emitted as structured events
   covering submit → admission → collate → bucket dispatch → per-chunk
   solve → artifact fetch (plus the fault events: retries, ladder level,
-  quarantine, deadline cuts, degraded answers).
+  quarantine, deadline cuts, degraded answers); ``region`` marks the
+  solve path's layers, also on the profiler's host timeline.
 * :mod:`repro.obs.profiler` — an opt-in ``jax.profiler`` trace-capture
   hook around a named dispatch.
 
@@ -32,7 +33,7 @@ from .metrics import (
     jsonable,
     now,
 )
-from .tracing import Span, Tracer, new_id, span_tree
+from .tracing import Span, Tracer, new_id, region, span_tree
 
 __all__ = [
     "Counter",
@@ -51,5 +52,6 @@ __all__ = [
     "new_id",
     "now",
     "profiler",
+    "region",
     "span_tree",
 ]

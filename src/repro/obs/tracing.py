@@ -14,6 +14,13 @@ attributes.  ``bind()`` derives a child tracer with different defaults —
 this is how the chunked drivers' per-chunk events get parented under the
 dispatch's solve span without the drivers knowing about scheduling.
 
+Spans also land on the profiler's host timeline: ``annotation(name)``
+enters ``jax.profiler.TraceAnnotation("repro." + name)``, on the clock of
+the device trace, and ``Tracer.span`` enters it around its body.
+``region(obs, name)`` is the one helper the solve path marks its layers
+with: nothing for ``obs=None``, a ``Span`` (with its annotation) for a
+``Tracer``, and the annotation alone for any other event emitter.
+
 Thread-safety: span ids come from ``itertools.count`` (atomic in
 CPython); a ``Span`` is only ever mutated by the thread that ends it;
 ``Tracer`` itself is immutable after construction.  Scan-exempt for
@@ -22,8 +29,8 @@ those reasons.
 from __future__ import annotations
 
 import itertools
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, Optional
+from contextlib import contextmanager, nullcontext
+from typing import Any, ContextManager, Dict, Iterator, Optional
 
 from .metrics import MetricsRegistry, now
 
@@ -70,11 +77,6 @@ class Span:
         payload.update(attrs)
         self._tracer.registry.emit("span", payload)
 
-    def child(self, tracer_attrs: bool = False) -> "Tracer":
-        """A tracer whose spans/events are parented under this span."""
-        return self._tracer.bind(trace_id=self.trace_id,
-                                 parent=self.span_id)
-
 
 class Tracer:
     """Factory for spans and structured events over one registry."""
@@ -118,9 +120,12 @@ class Tracer:
     @contextmanager
     def span(self, name: str, trace_id: Optional[str] = None,
              parent: Optional[int] = None, **attrs: Any) -> Iterator[Span]:
+        """A span around the body, also marked on the profiler's host
+        timeline (:func:`annotation`)."""
         s = self.start(name, trace_id=trace_id, parent=parent, **attrs)
         try:
-            yield s
+            with annotation(name):
+                yield s
         except BaseException as e:
             s.end(error=type(e).__name__)
             raise
@@ -137,6 +142,34 @@ class Tracer:
         payload.update(self.attrs)
         payload.update(attrs)
         self.registry.emit(kind, payload)
+
+
+@contextmanager
+def annotation(name: str) -> Iterator[None]:
+    """The body as a ``repro.<name>`` span on the profiler's host timeline
+    (``jax.profiler.TraceAnnotation``), on the same clock as the program
+    launches and device executions of a captured trace."""
+    import jax  # lazily: repro.obs imports only the stdlib
+
+    with jax.profiler.TraceAnnotation("repro." + name):
+        yield
+
+
+_NO_REGION = nullcontext()
+
+
+def region(obs, name: str, *, record: bool = True) -> ContextManager[Any]:
+    """Mark one layer of a solve call for ``obs``, the solve path's
+    optional event emitter: nothing when ``obs`` is None; a ``Span`` (with
+    its annotation) when it is a :class:`Tracer` and ``record`` is set;
+    the :func:`annotation` alone otherwise. ``record=False`` is for
+    regions the caller already reports to the sinks as an event of its
+    own, as the drivers do with each ``"chunk"``."""
+    if obs is None:
+        return _NO_REGION
+    if record and isinstance(obs, Tracer):
+        return obs.span(name)
+    return annotation(name)
 
 
 def span_tree(events, trace_id: Optional[str] = None) -> str:

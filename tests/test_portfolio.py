@@ -351,10 +351,9 @@ def test_solver_choice_obs_event_and_stats_surface():
                         policy=DispatchPolicy(mode="compact",
                                               solver="sinkhorn"),
                         obs=obs)
-    kinds = [k for k, _ in obs.kinds]
-    assert "solver-choice" in kinds
-    ev = dict(obs.kinds)["solver-choice"]
-    assert ev["solver"] == "sinkhorn"
+    # the choice rides the stats; the obs sees the driver's chunks
+    kinds = {k for k, _ in obs.kinds}
+    assert kinds == {"chunk"}
     assert stats.solver == "sinkhorn"
     assert stats.solve_s > 0
     # SolveStats surface carries the portfolio fields through as_dict

@@ -220,8 +220,8 @@ def dispatch(
     emitter (``repro.obs.Tracer``) into the chunked drivers; lockstep
     ignores it (one unbounded program, nothing per-chunk to report). The
     host prep (solver routing, the admission check, then each driver's
-    masking and upload) is marked as ``solve.prepare`` regions
-    (``repro.obs.region``).
+    thresholds, lane padding and upload) is marked as ``solve.prepare``
+    regions (``repro.obs.region``).
 
     ``policy.solver`` routes the bucket through the solver portfolio
     (push-relabel / Sinkhorn / hybrid / measured-auto); the chosen

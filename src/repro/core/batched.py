@@ -42,9 +42,10 @@ import numpy as np
 from .problem import (
     ASSIGNMENT,
     OT,
-    _mask_ot_inputs,
+    _ot_thresholds,
     _sizes_arrays,
     _theta_array,
+    mask_ot_padding,
     pow2_at_least,
 )
 from .pushrelabel import assignment_prologue, solve_assignment_int
@@ -154,21 +155,22 @@ def solve_assignment_batched(
 # --------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("eps",))
-def _ot_lockstep(c, nu, mu, theta, threshold, eps: float):
+def _ot_lockstep(c, nu, mu, m_valid, n_valid, theta, threshold, eps: float):
     """Prologue + integer solve of every lane in ONE vmapped program;
-    returns the ``OT`` epilogue context and the integer state."""
+    returns the float part of the ``OT`` epilogue context and the
+    integer state."""
 
-    def one(ci, nui, mui, ti, thi):
+    def one(ci, nui, mui, mv, nv, ti, thi):
+        ci, nui, mui = mask_ot_padding(ci, nui, mui, mv, nv)
         c_int, s_int, d_int, scale = ot_prologue(ci, nui, mui, ti, eps)
         nb, na = ci.shape
         st = solve_ot_int(c_int, s_int, d_int, eps, ot_phase_cap(eps),
                           max_rounds=int(nb + na + 2), threshold=thi)
-        ctx = {"c": ci, "nu": nui, "mu": mui, "theta": ti,
-               "eps": jnp.float32(eps), "scale": scale, "s_int": s_int,
-               "d_int": d_int}
+        ctx = {"theta": ti, "eps": jnp.float32(eps), "scale": scale,
+               "s_int": s_int, "d_int": d_int}
         return ctx, st
 
-    return jax.vmap(one)(c, nu, mu, theta, threshold)
+    return jax.vmap(one)(c, nu, mu, m_valid, n_valid, theta, threshold)
 
 
 def solve_ot_batched(
@@ -185,8 +187,8 @@ def solve_ot_batched(
 
     Args:
       c: (B, M, N) costs; nu: (B, M) supplies; mu: (B, N) demands. Instance i
-        occupies the leading ``sizes[i]`` block; padded rows/cols must carry
-        zero mass (they are zeroed defensively from ``sizes`` regardless).
+        occupies the leading ``sizes[i]`` block; whatever the padding holds
+        is masked to zero cost and mass inside the solve programs.
       eps: additive error parameter shared across the batch.
       sizes: optional host (B, 2) int array of true instance shapes - also
         sets the per-instance theta to the unbatched default 4*max(m,n)/eps.
@@ -204,9 +206,14 @@ def solve_ot_batched(
     b, m, n = c.shape
     m_valid, n_valid = _sizes_arrays(sizes, b, m, n)
     th = _theta_array(m_valid, n_valid, eps, theta)
-    c, nu, mu, thr = _mask_ot_inputs(c, nu, mu, m_valid, n_valid, th, eps)
-    ctx, state = _ot_lockstep(c, nu, mu, jnp.asarray(th), jnp.asarray(thr),
-                              eps)
+    thr = _ot_thresholds(nu, m_valid, th, eps)
+    m_valid, n_valid = jnp.asarray(m_valid), jnp.asarray(n_valid)
+    ctx, state = _ot_lockstep(c, nu, mu, m_valid, n_valid, jnp.asarray(th),
+                              jnp.asarray(thr), eps)
+    # the epilogue masks the caller's operands itself (as in the
+    # compacting driver, they are not round-tripped through the program)
+    ctx = {**ctx, "c": c, "nu": nu, "mu": mu, "m_valid": m_valid,
+           "n_valid": n_valid}
     return _epilogue(OT)(ctx, state)
 
 

@@ -398,8 +398,8 @@ def solve_ot_batched_compacting(
 ):
     """Compacting counterpart of ``solve_ot_batched``; binds ``OT`` to
     :func:`solve_compacting`. Same contract as the lockstep path
-    ((B, M, N) costs, (B, M)/(B, N) masses, padding zeroed from
-    ``sizes``), plus per-instance ``eps`` support. Returns
+    ((B, M, N) costs, (B, M)/(B, N) masses, padding masked from ``sizes``
+    inside the programs), plus per-instance ``eps`` support. Returns
     ``(OTResult with leading batch axes, CompactionStats)``."""
     return solve_compacting(OT, {"c": c, "nu": nu, "mu": mu}, eps,
                             sizes=sizes, k=k, guaranteed=guaranteed,

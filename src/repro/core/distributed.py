@@ -410,9 +410,9 @@ def solve_mesh(
         prologue_s, init_s, chunk_s, conv_s, epilogue_s = _mesh_fns(
             spec, mesh, batch_axis, k)
         _, _, chunk_1, conv_1, _ = spec_fns(spec, k)
-        # straight onto the mesh; dropping ``p`` frees any staged copy of
-        # the masked operands on the default device once the transfer is
-        # done
+        # straight onto the mesh; dropping ``p`` frees the lane-padded
+        # copy of the operands (built when bp > b) on the default device
+        # once the transfer is done
         ops = {kk: jax.device_put(v, sh) for kk, v in p.ops.items()}
         bp, phase_cap = p.bp, p.phase_cap
         del p

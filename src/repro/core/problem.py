@@ -12,7 +12,8 @@ bound to a problem by a spec object, instead of maintaining parallel
 
 Protocol methods, mapped to the paper's algorithm steps:
 
-  ``prepare``       host-side batch prep: padding masks, per-instance
+  ``prepare``       host-side batch prep: per-lane valid sizes (the
+                    prologue masks the padding with them), per-instance
                     eps/theta, the host-float64 termination thresholds
                     (``int(eps * m)`` for Algorithm 1; ``int(eps *
                     sum(s_int))`` for Algorithm 2) and phase-cap safety
@@ -200,28 +201,39 @@ def _theta_array(sizes_m, sizes_n, eps, theta) -> np.ndarray:
     return (4.0 * np.maximum(sizes_m, sizes_n) / eps).astype(np.float32)
 
 
-def _mask_ot_inputs(c, nu, mu, m_valid, n_valid, theta, eps):
-    """Zero mass/cost outside each instance's block and compute the
-    per-instance termination thresholds in host float64 from the masked
-    masses — identical to the unbatched solve_ot (the on-device f32
-    product rounds the wrong way for some (eps, total_mass) pairs).
+def _ot_thresholds(nu, m_valid, theta, eps) -> np.ndarray:
+    """(B,) int32 per-instance termination thresholds, computed on the
+    host in float64 from the masses of each instance's valid rows (the
+    rows at or past ``m_valid`` count as zero) — identical to the
+    unbatched solve_ot (the on-device f32 product rounds the wrong way
+    for some (eps, total_mass) pairs). Reads only the (B, M) masses.
     Shared by the lockstep and compacting paths so the two can never
-    diverge on threshold/masking semantics. ``eps`` scalar or (B,)."""
-    b, m, n = c.shape
+    diverge on the threshold. ``eps`` scalar or (B,)."""
+    nu = np.asarray(nu, np.float32)
+    b, m = nu.shape
     row_ok = np.arange(m)[None, :] < m_valid[:, None]
-    col_ok = np.arange(n)[None, :] < n_valid[:, None]
     eps_b = np.broadcast_to(np.asarray(eps, np.float64), (b,))
-    nu_h = np.where(row_ok, np.asarray(nu, np.float32), np.float32(0.0))
+    nu_h = np.where(row_ok, nu, np.float32(0.0))
     # vectorized ot_termination_threshold: f32 floor(nu * theta) per entry
     # (the device rounding), f64 row sums, f64 eps product, truncation
     s_rows = np.floor(nu_h * np.asarray(theta, np.float32)[:, None])
-    thr = (eps_b * s_rows.sum(axis=1, dtype=np.float64)).astype(np.int64) \
+    return (eps_b * s_rows.sum(axis=1, dtype=np.float64)).astype(np.int64) \
         .astype(np.int32)
-    mask = jnp.asarray(row_ok[:, :, None] & col_ok[:, None, :])
-    c = jnp.where(mask, c, 0.0)
-    nu = jnp.where(jnp.asarray(row_ok), nu, 0.0)
-    mu = jnp.where(jnp.asarray(col_ok), mu, 0.0)
-    return c, nu, mu, thr
+
+
+def mask_ot_padding(c, nu, mu, m_valid, n_valid):
+    """Zero one OT instance's costs and masses outside its leading
+    ``(m_valid, n_valid)`` block (traced, per lane). Every OT program
+    that reads ``c``/``nu``/``mu`` (the prologue, the epilogue, the
+    lockstep solve) masks through this first, so whatever the padding
+    holds (stale data, inf, NaN) never reaches ``max(c)``, the integer
+    instance or the plan's cost, and no masked copy of the batch is
+    built outside those programs."""
+    m, n = c.shape
+    row_ok = jnp.arange(m) < m_valid
+    col_ok = jnp.arange(n) < n_valid
+    c = jnp.where(row_ok[:, None] & col_ok[None, :], c, 0.0)
+    return c, jnp.where(row_ok, nu, 0.0), jnp.where(col_ok, mu, 0.0)
 
 
 def _pad_lanes(bp: int, b: int, arrays: Dict[str, Any],
@@ -510,11 +522,14 @@ class OTSpec:
 
     def prepare(self, inputs, eps, *, sizes=None, guaranteed: bool = False,
                 min_batch: int = 1, theta=None) -> PreparedBatch:
-        """OT counterpart of ``AssignmentSpec.prepare``: shares the
-        padding-mask + host-float64 threshold code with the lockstep path
-        (``_mask_ot_inputs``) so the code paths can never diverge. Batch
-        padding is born-converged (zero mass -> free supply 0 <=
-        threshold 0)."""
+        """OT counterpart of ``AssignmentSpec.prepare``. ``c``, ``nu``
+        and ``mu`` pass through as the caller's buffers: the padding
+        outside each lane's ``(m_valid, n_valid)`` block is masked inside
+        the prologue and epilogue programs (``mask_ot_padding``), so no
+        (B, M, N) array is built here, on the host or the device. The
+        thresholds are host float64 from the masses (``_ot_thresholds``,
+        shared with the lockstep path). Batch padding is born-converged
+        (``m_valid = 0``: zero mass -> free supply 0 <= threshold 0)."""
         c, nu, mu = inputs["c"], inputs["nu"], inputs["mu"]
         b, m, n = c.shape
         m_valid, n_valid = _sizes_arrays(sizes, b, m, n)
@@ -522,13 +537,14 @@ class OTSpec:
         th = _theta_array(m_valid, n_valid, eps_arr, theta)
         phase_cap = np.asarray([ot_phase_cap(float(e)) for e in eps_arr],
                                np.int32)
-        c, nu, mu, threshold = _mask_ot_inputs(c, nu, mu, m_valid, n_valid,
-                                               th, eps_arr)
+        threshold = _ot_thresholds(nu, m_valid, th, eps_arr)
         bp = max(pow2_at_least(b), pow2_at_least(min_batch))
         ops = _pad_lanes(bp, b, {
             "c": c, "nu": nu, "mu": mu,
             "eps": eps_arr.astype(np.float32),
             "theta": th,
+            "m_valid": m_valid,
+            "n_valid": n_valid,
             "threshold": threshold,
             "phase_cap": phase_cap,
         }, fills={"eps": np.float32(eps_arr[0]), "theta": np.float32(1.0)})
@@ -541,11 +557,13 @@ class OTSpec:
 
     # -- per-instance jax functions ------------------------------------
 
-    ctx_ops = ("c", "nu", "mu", "theta", "eps")
+    ctx_ops = ("c", "nu", "mu", "theta", "eps", "m_valid", "n_valid")
 
     def prologue(self, ops):
-        c_int, s_int, d_int, scale = ot_prologue(
-            ops["c"], ops["nu"], ops["mu"], ops["theta"], ops["eps"])
+        c, nu, mu = mask_ot_padding(ops["c"], ops["nu"], ops["mu"],
+                                    ops["m_valid"], ops["n_valid"])
+        c_int, s_int, d_int, scale = ot_prologue(c, nu, mu, ops["theta"],
+                                                 ops["eps"])
         data = {"c_int": c_int, "threshold": ops["threshold"],
                 "phase_cap": ops["phase_cap"]}
         ctx = {"scale": scale, "s_int": s_int, "d_int": d_int}
@@ -563,9 +581,10 @@ class OTSpec:
         return ot_converged(state, data["threshold"], data["phase_cap"])
 
     def epilogue(self, ctx, state):
-        return ot_epilogue(ctx["c"], ctx["nu"], ctx["mu"], ctx["theta"],
-                           ctx["eps"], ctx["scale"], ctx["s_int"],
-                           ctx["d_int"], state)
+        c, nu, mu = mask_ot_padding(ctx["c"], ctx["nu"], ctx["mu"],
+                                    ctx["m_valid"], ctx["n_valid"])
+        return ot_epilogue(c, nu, mu, ctx["theta"], ctx["eps"],
+                           ctx["scale"], ctx["s_int"], ctx["d_int"], state)
 
     # -- result shaping ------------------------------------------------
 
@@ -882,9 +901,10 @@ def _trace_assignment_state_chain():
 def _trace_ot_state_chain():
     m = n = 8
 
-    def chain(c, nu, mu, theta, eps):
+    def chain(c, nu, mu, theta, eps, m_valid, n_valid):
         data, ctx = OT.prologue({
             "c": c, "nu": nu, "mu": mu, "theta": theta, "eps": eps,
+            "m_valid": m_valid, "n_valid": n_valid,
             "threshold": jnp.int32(0), "phase_cap": jnp.int32(8)})
         state = OT.init_state(data, ctx)
         return {"state": state,
@@ -901,9 +921,11 @@ def _trace_ot_state_chain():
             "mu": jnp.full((n,), 1.0 / n, jnp.float32),
             "theta": jnp.float32(4.0 * m / 0.1),
             "eps": jnp.float32(0.1),
+            "m_valid": jnp.int32(m),
+            "n_valid": jnp.int32(n),
         },
         retained={"c", "nu", "mu"},
-        must_trace={"eps", "theta"},
+        must_trace={"eps", "theta", "m_valid", "n_valid"},
         tags={"state-init-chain", "ot"},
         source=__name__,
     )

@@ -173,11 +173,10 @@ def dispatch_hybrid(
     warm = st1.final_state
 
     # stage 2: round the potentials onto the finish solve's integer grid
-    # (the INTERNAL eps: /3 under the guaranteed contract). The rounding
-    # sees the same masked operands the specs' prepare builds, because
-    # stage 1 ran on the canonicalized inputs whose padding the Sinkhorn
-    # prologue already zeroed via its prepare masks — f/g outside the
-    # valid block are inert and the clip bounds them anyway.
+    # (the INTERNAL eps: /3 under the guaranteed contract). Stage 1 ran
+    # on the canonicalized inputs with their padding zeroed by the
+    # Sinkhorn prepare's masks — f/g outside the valid block are inert
+    # and the clip bounds them anyway.
     eps_int = jnp.asarray(eps_array(eps_user, b, policy.guaranteed),
                           jnp.float32)
     y_b0 = round_duals(inputs["c"], inputs["mu"], warm.f, warm.g, eps_int)
@@ -228,9 +227,10 @@ def _trace_round_duals():
 def _trace_warm_state_chain():
     m = n = 8
 
-    def chain(c, nu, mu, theta, eps, y_b0):
+    def chain(c, nu, mu, theta, eps, m_valid, n_valid, y_b0):
         data, ctx = WARM_OT.prologue({
             "c": c, "nu": nu, "mu": mu, "theta": theta, "eps": eps,
+            "m_valid": m_valid, "n_valid": n_valid,
             "threshold": jnp.int32(0), "phase_cap": jnp.int32(64)})
         ctx = {**ctx, "y_b0": y_b0}
         state = WARM_OT.init_state(data, ctx)
@@ -249,6 +249,8 @@ def _trace_warm_state_chain():
             "mu": jnp.full((n,), 1.0 / n, jnp.float32),
             "theta": jnp.float32(320.0),
             "eps": jnp.float32(0.1),
+            "m_valid": jnp.int32(m),
+            "n_valid": jnp.int32(n),
             "y_b0": jnp.ones((m,), jnp.int32),
         },
         retained={"c", "nu", "mu", "y_b0"},

@@ -139,13 +139,17 @@ def _drive(data, state, run_fn, conv_fn, max_chunks: int,
 
     ``obs`` is an optional event emitter (duck-typed
     ``repro.obs.Tracer``): one ``"chunk"`` event per dispatch carrying
-    the batch bucket, live-lane count, wall time, max phase delta, and
-    the chunk program's jit-cache delta (nonzero exactly when this
-    dispatch compiled), plus a ``"deadline-cut"`` event when the budget
-    stops the loop. Each chunk, launch through the conv fetch, is also a
-    ``solve.chunk`` region on the profiler's host timeline
-    (``repro.obs.region``; the ``"chunk"`` event is its record for the
-    sinks). Everything emitted is a host scalar the loop already had —
+    the batch bucket, live-lane count, wall time, max phase delta, the
+    chunk program's jit-cache delta (nonzero exactly when this dispatch
+    compiled) and ``rebucket_s``, the host seconds of the re-bucket that
+    ran since the previous chunk event (0.0 if none: the event stays
+    right after the conv fetch, so a re-bucket rides on the next one),
+    plus a ``"deadline-cut"`` event when the budget stops the loop. Each
+    chunk, launch through the conv fetch, is also a ``solve.chunk``
+    region on the profiler's host timeline (``repro.obs.region``; the
+    ``"chunk"`` event is its record for the sinks), and each re-bucket,
+    flush through the survivor gather, a ``solve.rebucket`` region.
+    Everything emitted is a host scalar the loop already had —
     observability adds no device->host syncs (the sync audit holds this
     loop to the single conv fetch either way)."""
     idx = np.arange(stats.dispatched_batch)
@@ -159,6 +163,7 @@ def _drive(data, state, run_fn, conv_fn, max_chunks: int,
     buf = None
     cur_d, cur_s = data, state
     ph_prev = np.zeros((stats.dispatched_batch,), np.int64)
+    rebucket_s = 0.0
     for _ in range(max_chunks):
         with region(obs, "solve.chunk", record=False):
             t_chunk = _now()
@@ -177,8 +182,10 @@ def _drive(data, state, run_fn, conv_fn, max_chunks: int,
         if obs is not None:
             cache_now = cache_fn() if cache_fn is not None else 0
             obs.event("chunk", bucket=bb, live=live, chunk_s=t_chunk,
-                      phases=dph, compiled=cache_now - cache_prev)
+                      phases=dph, compiled=cache_now - cache_prev,
+                      rebucket_s=rebucket_s)
             cache_prev = cache_now
+        rebucket_s = 0.0
         if live == 0:
             buf = cur_s if buf is None else _scatter(buf, cur_s,
                                                      jnp.asarray(idx))
@@ -210,14 +217,17 @@ def _drive(data, state, run_fn, conv_fn, max_chunks: int,
             # data-dependent lane count), then gather survivors (padded
             # with one converged lane, which is inert — its predicate is
             # already false) into the next bucket.
-            buf = cur_s if buf is None else _scatter(buf, cur_s,
-                                                     jnp.asarray(idx))
-            surv = np.flatnonzero(~conv)
-            fill = np.flatnonzero(conv)[:1]
-            sel = np.concatenate([surv, np.repeat(fill, nb - live)])
-            sel_j = jnp.asarray(sel)
-            cur_d = _gather(cur_d, sel_j)
-            cur_s = _gather(cur_s, sel_j)
+            with region(obs, "solve.rebucket", record=False):
+                t_re = _now()
+                buf = cur_s if buf is None else _scatter(buf, cur_s,
+                                                         jnp.asarray(idx))
+                surv = np.flatnonzero(~conv)
+                fill = np.flatnonzero(conv)[:1]
+                sel = np.concatenate([surv, np.repeat(fill, nb - live)])
+                sel_j = jnp.asarray(sel)
+                cur_d = _gather(cur_d, sel_j)
+                cur_s = _gather(cur_s, sel_j)
+                rebucket_s = _now() - t_re
             idx = idx[sel]
             ph_prev = ph[sel]
     else:

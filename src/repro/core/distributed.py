@@ -222,10 +222,14 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
     the state buffers (one copy of solver state per bucket, not two).
     ``deadline`` is an absolute monotonic (``repro.obs.now``) budget with
     the same best-so-far cut semantics as compaction._drive. ``obs`` is
-    the same optional per-chunk event emitter as compaction._drive (the
-    ``"chunk"`` events additionally carry the device count this dispatch
-    ran on, and each chunk is a ``solve.chunk`` region); events are host
-    scalars only — no extra device syncs."""
+    the same optional per-chunk event emitter as compaction._drive, with
+    the same ``solve.chunk`` and ``solve.rebucket`` regions and
+    ``rebucket_s`` (here the re-bucket is the flush, the survivor gather
+    and the re-shard or the collapse); the ``"chunk"`` events also carry
+    the device count this dispatch ran on and ``chip_phases``, each
+    device's local max phase delta (the slowest sets ``phases``, and the
+    others wait for it). Events are host values the loop already had — no
+    extra device syncs."""
     d0 = int(mesh.shape[axis])
     cache_fns = ({id(run_s): getattr(run_s, "_cache_size", None),
                   id(run_1): getattr(run_1, "_cache_size", None)}
@@ -253,6 +257,7 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
                                        jax.device_put(pos, sh))
 
     ph_prev = np.zeros((stats.dispatched_batch,), np.int64)
+    rebucket_s = 0.0
     for _ in range(max_chunks):
         with region(obs, "solve.chunk", record=False):
             t_chunk = _now()
@@ -272,10 +277,8 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
         stats.devices_per_dispatch.append(d_now)
         # per-device lockstep accounting: each device's vmapped while_loop
         # runs its local lanes for the LOCAL max phase delta
-        per_dev = (ph - ph_prev).reshape(d_now, bb // d_now)
-        stats.slot_phases += int(
-            (per_dev.max(axis=1) * (bb // d_now)).sum()
-        )
+        chip_phases = (ph - ph_prev).reshape(d_now, bb // d_now).max(axis=1)
+        stats.slot_phases += int(chip_phases.sum()) * (bb // d_now)
         ph_prev = ph
         live = int((~conv).sum())
         stats.occupancy.append((bb, live))
@@ -283,10 +286,12 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
             cf = cache_fns.get(id(run_fn))
             cache_now = cf() if cf is not None else 0
             obs.event("chunk", bucket=bb, live=live, chunk_s=t_chunk,
-                      phases=int(per_dev.max(initial=0)),
-                      devices=d_now,
-                      compiled=cache_now - cache_prev.get(id(run_fn), 0))
+                      phases=int(chip_phases.max()),
+                      devices=d_now, chip_phases=chip_phases.tolist(),
+                      compiled=cache_now - cache_prev.get(id(run_fn), 0),
+                      rebucket_s=rebucket_s)
             cache_prev[id(run_fn)] = cache_now
+        rebucket_s = 0.0
         if live == 0:
             buf = flush(buf, cur_s, idx)
             break
@@ -307,23 +312,26 @@ def _drive_distributed(data, state, run_s, conv_s, run_1, conv_1,
             # flush ALL lanes (fixed-length scatter; see compaction._drive),
             # then gather survivors + one inert converged filler lane and
             # re-bucket under the explicit device-put policy.
-            buf = flush(buf, cur_s, idx)
-            surv = np.flatnonzero(~conv)
-            fill = np.flatnonzero(conv)[:1]
-            sel = np.concatenate([surv, np.repeat(fill, nb - live)])
-            sel_j = jnp.asarray(sel)
-            cur_d = _gather(cur_d, sel_j)
-            cur_s = _gather(cur_s, sel_j)
-            if sharded and nb < d0:
-                # below the mesh floor: replicated single-device dispatch
-                cur_d = _put(cur_d, dev0)
-                cur_s = _put(cur_s, dev0)
-                sharded = False
-                stats.collapsed_at = nb
-            elif sharded:
-                # explicit re-shard of the shrunken bucket across the mesh
-                cur_d = _put(cur_d, sh)
-                cur_s = _put(cur_s, sh)
+            with region(obs, "solve.rebucket", record=False):
+                t_re = _now()
+                buf = flush(buf, cur_s, idx)
+                surv = np.flatnonzero(~conv)
+                fill = np.flatnonzero(conv)[:1]
+                sel = np.concatenate([surv, np.repeat(fill, nb - live)])
+                sel_j = jnp.asarray(sel)
+                cur_d = _gather(cur_d, sel_j)
+                cur_s = _gather(cur_s, sel_j)
+                if sharded and nb < d0:
+                    # below the mesh floor: replicated single-device dispatch
+                    cur_d = _put(cur_d, dev0)
+                    cur_s = _put(cur_s, dev0)
+                    sharded = False
+                    stats.collapsed_at = nb
+                elif sharded:
+                    # explicit re-shard of the shrunken bucket across the mesh
+                    cur_d = _put(cur_d, sh)
+                    cur_s = _put(cur_s, sh)
+                rebucket_s = _now() - t_re
             idx = idx[sel]
             ph_prev = ph[sel]
     else:

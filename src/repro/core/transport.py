@@ -69,7 +69,14 @@ class OTResult(NamedTuple):
 def _grant_round(c_int, y_b, ya_hi, rem_b, cap_a, salt):
     """One propose/accept round. Every b with remaining free supply proposes
     all of it to one hash-random admissible column with remaining capacity;
-    columns grant FIFO by row order via a segmented exclusive prefix sum."""
+    columns grant FIFO by row order.
+
+    A proposal's prefix is the exclusive cumsum of ALL proposing rows minus
+    that of its column's first proposer, not a prefix over the column's own
+    proposers: it over-counts whatever other columns' rows sit between them,
+    so a column may stop short of its capacity and take further partial
+    grants in later rounds of the same phase. The fused kernel
+    (``kernels/fused_phase``) and every recorded answer follow this rule."""
     nb, na = c_int.shape
     adm = (y_b[:, None] + ya_hi[None, :] == c_int + 1) & (cap_a[None, :] > 0)
     keys = proposal_keys(nb, na, salt)
@@ -78,7 +85,6 @@ def _grant_round(c_int, y_b, ya_hi, rem_b, cap_a, salt):
     can = jnp.any(adm, axis=1) & (rem_b > 0)
     tgt = jnp.where(can, best, jnp.int32(-1))
 
-    # Segmented exclusive prefix of proposal amounts, ordered by row index.
     amt = jnp.where(can, rem_b, 0)
     cums = jnp.cumsum(amt)
     excl = cums - amt
@@ -93,6 +99,12 @@ def _grant_round(c_int, y_b, ya_hi, rem_b, cap_a, salt):
     return tgt_safe, grant, jnp.any(can)
 
 
+# Rows of a phase's round log, R = min(_LOG_ROUNDS, max_rounds): nearly
+# every phase of DOTmark-like OT fits one block of 32 rounds, and the log
+# is 32/na of a flow matrix.
+_LOG_ROUNDS = 32
+
+
 def _phase(c_int, s: OTState, max_rounds: int) -> OTState:
     nb, na = c_int.shape
     free_b0, free_a0 = s.free_b, s.free_a
@@ -102,34 +114,54 @@ def _phase(c_int, s: OTState, max_rounds: int) -> OTState:
     cap0 = jnp.where(s.ya_hi == 0, s.free_a, 0) + m_hi
     # Guard: free_a > 0 implies ya_hi == 0, so the where() is redundant by the
     # invariant but keeps the state safe if it is ever perturbed.
-    granted0 = jnp.zeros((nb, na), jnp.int32)
 
-    def cond(c):
-        rem_b, cap_a, granted, rounds, done = c
-        return (~done) & (rounds < max_rounds)
+    # The round loop carries (nb,) vectors and logs each round's grants in
+    # an (n_log, nb) buffer, added into the flows once per block of at most
+    # n_log rounds: no (nb, na) array rides in the round carry, which the
+    # drivers' vmap would otherwise select (copy) whole every round.
+    n_log = min(_LOG_ROUNDS, max_rounds)
+    rows = jnp.broadcast_to(jnp.arange(nb, dtype=jnp.int32), (n_log, nb))
 
-    def body(c):
-        rem_b, cap_a, granted, rounds, _ = c
+    def round_cond(c):
+        rem_b, cap_a, rounds, done, k, log_tgt, log_grant = c
+        return (~done) & (rounds < max_rounds) & (k < n_log)
+
+    def round_body(c):
+        rem_b, cap_a, rounds, _, k, log_tgt, log_grant = c
         salt = s.phases * jnp.int32(7919) + rounds
         tgt_safe, grant, any_prop = _grant_round(
             c_int, s.y_b, s.ya_hi, rem_b, cap_a, salt
         )
-        rows = jnp.arange(nb, dtype=jnp.int32)
-        granted = granted.at[rows, jnp.clip(tgt_safe, 0, na - 1)].add(
-            jnp.where(tgt_safe < na, grant, 0)
-        )
         cap_a = cap_a.at[tgt_safe].add(-grant, mode="drop")
         rem_b = rem_b - grant
-        return (rem_b, cap_a, granted, rounds + 1, ~any_prop)
+        return (rem_b, cap_a, rounds + 1, ~any_prop, k + 1,
+                log_tgt.at[k].set(tgt_safe), log_grant.at[k].set(grant))
 
-    rem_b, cap_a, granted, rounds, _ = jax.lax.while_loop(
-        cond,
-        body,
-        vary_like((free_b0, cap0, granted0, jnp.int32(0), jnp.bool_(False)),
-                  c_int, *s),
+    def block(c):
+        rem_b, cap_a, rounds, done, flows = c
+        rem_b, cap_a, rounds, done, _, log_tgt, log_grant = jax.lax.while_loop(
+            round_cond,
+            round_body,
+            vary_like((rem_b, cap_a, rounds, done, jnp.int32(0),
+                       jnp.full((n_log, nb), na, jnp.int32),
+                       jnp.zeros((n_log, nb), jnp.int32)),
+                      c_int, *s),
+        )
+        # Unused log rows hold tgt = na, dropped by the scatter.
+        flows = flows.at[rows, log_tgt].add(log_grant, mode="drop")
+        return rem_b, cap_a, rounds, done, flows
+
+    # The first block runs unconditionally; further blocks only for a phase
+    # longer than n_log rounds, so under vmap the block loop (and its select
+    # over the flows) runs no body unless some lane needs one.
+    rem_b, cap_a, rounds, _, f_granted = jax.lax.while_loop(
+        lambda c: (~c[3]) & (c[2] < max_rounds),
+        block,
+        vary_like(block((free_b0, cap0, jnp.int32(0), jnp.bool_(False),
+                         s.f_lo)), c_int, *s),
     )
 
-    g_a = jnp.sum(granted, axis=0)                       # units matched in M'
+    g_a = cap0 - cap_a                                   # units matched in M'
     use_free = jnp.minimum(g_a, jnp.where(s.ya_hi == 0, free_a0, 0))
     disp = g_a - use_free                                # displaced hi flow
     # Victims: strip `disp` units off each column of f_hi, bottom rows first.
@@ -147,8 +179,9 @@ def _phase(c_int, s: OTState, max_rounds: int) -> OTState:
     hi_left = jnp.where(s.ya_hi == 0, free_a, 0) + jnp.sum(f_hi, axis=0)
     collapse = (hi_left == 0) & (g_a > 0)
     ya_hi = jnp.where(collapse, s.ya_hi - 1, s.ya_hi)
-    f_hi_new = jnp.where(collapse[None, :], s.f_lo + granted, f_hi)
-    f_lo_new = jnp.where(collapse[None, :], 0, s.f_lo + granted)
+    # f_granted is s.f_lo plus this phase's grants.
+    f_hi_new = jnp.where(collapse[None, :], f_granted, f_hi)
+    f_lo_new = jnp.where(collapse[None, :], 0, f_granted)
 
     # Relabel (III(b)): rows of B' with free supply left after M' rise by one.
     rem_after = rem_b

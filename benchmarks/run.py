@@ -82,7 +82,7 @@ def main() -> None:
                     help="paper-scale grids (slow on CPU)")
     ap.add_argument("--only", default=None,
                     help="comma-separated subset: synthetic,mnist,"
-                         "routing,ot,batched,sharded,solution,faults,"
+                         "ot,batched,sharded,solution,faults,"
                          "serve,kernels,portfolio")
     ap.add_argument("--diff", action="store_true",
                     help="compare fresh batched/sharded results against "
@@ -91,7 +91,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from . import bench_synthetic, bench_mnist, \
-        bench_routing, bench_ot, bench_batched, bench_sharded, \
+        bench_ot, bench_batched, bench_sharded, \
         bench_solution, bench_faults, bench_serve, bench_kernels, \
         bench_portfolio
 
@@ -99,7 +99,6 @@ def main() -> None:
         "synthetic": bench_synthetic.run,   # paper Fig. 1
         "mnist": bench_mnist.run,           # paper Fig. 2
         "ot": bench_ot.run,                 # Section 4 clustered solver
-        "routing": bench_routing.run,       # framework integration
         "batched": bench_batched.run,       # batched serving subsystem
         "sharded": bench_sharded.run,       # mesh-distributed dispatch
         "solution": bench_solution.run,     # typed result surface fetch

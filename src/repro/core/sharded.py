@@ -164,8 +164,8 @@ def _ot_solve_fn(mesh: Mesh, row_axis: str, col_axis: str, eps: float,
 
 def lower_sharded_solver(n: int, eps: float, mesh: Mesh,
                          row_axis="data", col_axis="model"):
-    """AOT artifact for the dry-run/roofline path: lower + compile the phase
-    loop for an (n, n) cost matrix on `mesh` without allocating it."""
+    """Lower the sharded phase loop for an (n, n) cost matrix on `mesh`
+    without allocating the matrix; ``.compile()`` on the result compiles it."""
     sds = jax.ShapeDtypeStruct(
         (n, n), jnp.int32,
         sharding=NamedSharding(mesh, P(row_axis, col_axis)),

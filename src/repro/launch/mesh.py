@@ -1,5 +1,6 @@
-"""Production mesh builders. FUNCTIONS (not module constants) so importing
-never touches jax device state.
+"""Mesh builders for the batch-sharded and matrix-placed solve paths.
+FUNCTIONS (not module constants) so importing never touches jax device
+state.
 """
 from __future__ import annotations
 
@@ -13,22 +14,8 @@ def _make_mesh(shape, axes, devices):
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = math.prod(shape)
-    devices = jax.devices()[:n]
-    if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices for the production mesh, have "
-            f"{len(jax.devices())} - run under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=512"
-        )
-    return _make_mesh(shape, axes, devices)
-
-
 def make_small_mesh(shape=(2, 4), axes=("data", "model")):
-    """CI-scale mesh for dry-run smoke tests (8 forced host devices)."""
+    """CI-scale 2-D mesh for the sharded-solver tests (8 forced host devices)."""
     n = math.prod(shape)
     return _make_mesh(shape, axes, jax.devices()[:n])
 

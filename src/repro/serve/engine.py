@@ -1,106 +1,18 @@
-"""Batched serving engine: prefill a batch of (padded) prompts, then greedy/
-temperature decode with per-sequence stopping. Also exposes the paper's OT
-solver as a batched endpoint (cost matrices via the Pallas kernel path on
-TPU), mirroring the paper's experiment harness as a service."""
+"""The paper's OT solver as a synchronous batched endpoint: ``OTService``
+queues point-set (assignment) and mass (general OT) requests, buckets them
+by shape and solves each bucket through ``core/api.solve``, with cost
+matrices built by the Pallas kernel on TPU. ``OTRequest`` is one queued
+request."""
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.models import model as M
 from repro.obs import MetricsRegistry, Tracer, new_id
 from repro.obs import now as _now
-
-
-@dataclass
-class Request:
-    prompt: np.ndarray                 # (L,) int32
-    max_new_tokens: int = 16
-    eos_id: Optional[int] = None
-
-
-@dataclass
-class Completion:
-    tokens: np.ndarray
-    prefill_len: int
-    decode_steps: int
-    latency_s: float
-
-
-class Engine:
-    """Synchronous batched engine: submit() queues requests; run_batch()
-    pads them to a common prompt length, prefills once, and decodes the
-    whole batch in lockstep with per-sequence early-stop masking."""
-
-    def __init__(self, cfg, params, max_len: int = 512):
-        self.cfg = cfg
-        self.params = params
-        self.max_len = max_len
-        self.queue: List[Request] = []
-        self._decode = jax.jit(
-            lambda p, c, t, pos: M.decode_step(p, cfg, c, t, pos)
-        )
-        self._prefill = jax.jit(lambda p, b: M.prefill(p, cfg, b))
-
-    def submit(self, req: Request):
-        self.queue.append(req)
-
-    def run_batch(self) -> List[Completion]:
-        if not self.queue:
-            return []
-        reqs, self.queue = self.queue, []
-        t0 = _now()
-        b = len(reqs)
-        plen = max(len(r.prompt) for r in reqs)
-        toks = np.zeros((b, plen), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, plen - len(r.prompt):] = r.prompt  # left-pad
-        caches, logits = self._prefill(self.params, {"tokens": jnp.asarray(toks)})
-        caches = M.pad_caches(self.cfg, caches, self.max_len)
-        max_new = max(r.max_new_tokens for r in reqs)
-        out = np.zeros((b, max(max_new, 1)), np.int32)
-        # max_new_tokens=0 requests are complete before the first step
-        done = np.asarray([r.max_new_tokens <= 0 for r in reqs])
-        # Per-sequence accounting: the batch decodes in lockstep, but each
-        # request's tokens end at its own EOS / max_new_tokens, its
-        # decode_steps is the number of steps it was live, and its latency
-        # is the wall time until *its* completion (not the whole batch's).
-        steps_per_seq = np.zeros((b,), np.int32)
-        finish_time = np.full((b,), np.nan)
-        cur = jnp.argmax(logits[:, : self.cfg.vocab_size], -1)[:, None]
-        cur = cur.astype(jnp.int32)
-        for t in range(max_new):
-            out[:, t] = np.asarray(cur[:, 0])
-            now = _now()
-            for i, r in enumerate(reqs):
-                if done[i]:
-                    continue
-                steps_per_seq[i] = t + 1
-                hit_eos = r.eos_id is not None and out[i, t] == r.eos_id
-                if hit_eos or t + 1 >= r.max_new_tokens:
-                    done[i] = True
-                    finish_time[i] = now
-            if done.all() or plen + t + 1 >= self.max_len:
-                break
-            logits, caches = self._decode(
-                self.params, caches, cur, jnp.int32(plen + t)
-            )
-            cur = jnp.argmax(
-                logits[:, : self.cfg.vocab_size], -1
-            )[:, None].astype(jnp.int32)
-        t_end = _now()
-        finish_time = np.where(np.isnan(finish_time), t_end, finish_time)
-        return [
-            Completion(tokens=out[i, : steps_per_seq[i]],
-                       prefill_len=plen,
-                       decode_steps=int(steps_per_seq[i]),
-                       latency_s=float(finish_time[i] - t0))
-            for i in range(b)
-        ]
 
 
 @dataclass
@@ -114,9 +26,9 @@ class OTRequest:
 class OTService:
     """Batched OT-distance endpoint (the paper's solver as a service).
 
-    Mirrors ``Engine``: ``submit()`` queues distance requests; ``run_batch()``
-    groups them into shape buckets, pads each bucket to a fixed shape, and
-    dispatches every bucket through the unified ``core/api.solve`` front
+    ``submit()`` queues distance requests; ``run_batch()`` groups them into
+    shape buckets, pads each bucket to a fixed shape, and dispatches every
+    bucket through the unified ``core/api.solve`` front
     door (one DispatchPolicy per service: lockstep / compacting / mesh-
     distributed, chosen by the constructor arguments below). With
     ``compact=True`` (default) a bucket is solved by the convergence-

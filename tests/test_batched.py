@@ -223,3 +223,14 @@ def test_ot_service_bucketed_queue():
     c = build_cost_matrix(jnp.asarray(x), jnp.asarray(y), "euclidean")
     s = solve_ot(c, jnp.asarray(nu), jnp.asarray(mu), 0.1)
     assert res[4]["cost"] == pytest.approx(float(s.cost), abs=2e-6)
+
+
+def test_ot_service_endpoint():
+    from repro.serve.engine import OTService
+
+    rng = np.random.default_rng(1)
+    svc = OTService(eps=0.1)
+    out = svc.distance(rng.uniform(size=(64, 2)).astype(np.float32),
+                       rng.uniform(size=(64, 2)).astype(np.float32))
+    assert out["cost"] >= out["dual_lower_bound"] - 0.35  # weak duality + eps
+    assert len(np.unique(out["matching"])) == 64

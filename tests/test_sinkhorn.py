@@ -53,3 +53,24 @@ def test_kernel_variant_underflows_at_small_reg():
 
 def test_reg_heuristic_monotone():
     assert reg_for_additive_eps(0.1, 100) > reg_for_additive_eps(0.01, 100)
+
+
+def test_sinkhorn_kernel_in_solver_loop():
+    """Pallas sinkhorn_row_update drops into the log-domain loop."""
+    from repro.kernels import ops
+    from repro.core.costs import build_cost_matrix
+
+    rng = np.random.default_rng(0)
+    n = 96
+    c = build_cost_matrix(jnp.asarray(rng.uniform(size=(n, 2))),
+                          jnp.asarray(rng.uniform(size=(n, 2))), "euclidean")
+    nu = jnp.full((n,), 1.0 / n)
+    log_nu = jnp.log(nu)
+    reg = 0.05
+    f = jnp.zeros((n,))
+    g = jnp.zeros((n,))
+    for _ in range(80):
+        f = ops.sinkhorn_row_update(c, g, log_nu, reg)
+        g = ops.sinkhorn_row_update(c.T, f, log_nu, reg)
+    plan = jnp.exp((f[:, None] + g[None, :] - c) / reg)
+    assert float(jnp.abs(plan.sum(1) - nu).sum()) < 2e-2
